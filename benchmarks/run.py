@@ -9,6 +9,7 @@ benchmarks/results/.
 from __future__ import annotations
 
 import argparse
+import os
 import time
 import traceback
 
@@ -24,8 +25,17 @@ def main() -> None:
     train_steps = 1200 if args.full else 150
     ft_steps = 300 if args.full else 40
 
+    # One process holds the devices for every experiment; on the CPU the
+    # LP-speed mesh comes from eight forced host devices (the flag only
+    # affects the host platform, so it is harmless on an accelerator).
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
+    import jax
+
     from benchmarks import (effective_depth, finetune_recovery, icl_depth,
                             lp_ppl_sweep, lp_speed)
+    n_dev = len(jax.devices())
+    speed_mesh = "2x4" if n_dev >= 8 else f"1x{n_dev}"
     experiments = {
         # paper Fig. 3/4
         "effective_depth": lambda: effective_depth.run(
@@ -38,7 +48,7 @@ def main() -> None:
         "finetune_recovery": lambda: finetune_recovery.run(
             train_steps=train_steps, ft_steps=ft_steps),
         # paper Fig. 7/8 + Table 3 / Appendix C
-        "lp_speed": lambda: lp_speed.run(),
+        "lp_speed": lambda: lp_speed.run(mesh=speed_mesh, in_process=True),
     }
     print("name,seconds,status")
     rows = []

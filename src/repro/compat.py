@@ -1,97 +1,18 @@
-"""JAX API compatibility shim.
+"""Pallas ``interpret`` switch.
 
-The repo targets the current JAX API surface but must run on older
-releases baked into the container. Everything version-dependent is
-resolved HERE, once, so the rest of the codebase imports stable names:
-
-  shard_map            — ``jax.shard_map`` (new) vs
-                         ``jax.experimental.shard_map.shard_map`` (old);
-                         also translates the ``check_vma=`` kwarg (new
-                         name) to ``check_rep=`` (old name).
-  tpu_compiler_params  — ``pltpu.CompilerParams`` (new) vs
-                         ``pltpu.TPUCompilerParams`` (old).
-  default_interpret    — Pallas ``interpret`` auto-detection: compiled on
-                         TPU, interpreter everywhere else, so the same
-                         kernel call sites run on CPU CI and on hardware.
-
-Keep this module dependency-light: it is imported by the kernels and the
-sharded entry points before anything else in the package.
+Every kernel takes ``interpret=None``, which resolves here: compiled on a
+TPU backend, the Pallas interpreter elsewhere, so the CPU test suite runs
+the same kernel call sites the chip compiles. The chip path never relies
+on this switch for evidence: ``chip_smoke.py`` asserts that the compiled
+decode program holds a ``tpu_custom_call``.
 """
 from __future__ import annotations
 
-import functools
-from typing import Any
-
 import jax
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-else:  # pre-0.6 JAX: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def _accepts_kwarg(fn, name: str) -> bool:
-    import inspect
-    try:
-        return name in inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # C accelerated / wrapped callables
-        return False
-
-
-_HAS_CHECK_VMA = _accepts_kwarg(_shard_map_impl, "check_vma")
-_HAS_CHECK_REP = _accepts_kwarg(_shard_map_impl, "check_rep")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
-    """Version-stable ``shard_map``.
-
-    ``check_vma`` follows the new-JAX spelling; on older releases it is
-    forwarded as ``check_rep`` (same semantics: disable the replication /
-    varying-manual-axes check), and dropped entirely if neither kwarg
-    exists.
-    """
-    kw: dict[str, Any] = {}
-    if check_vma is not None:
-        if _HAS_CHECK_VMA:
-            kw["check_vma"] = check_vma
-        elif _HAS_CHECK_REP:
-            kw["check_rep"] = check_vma
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
-
-# Install the modern alias so call sites (and REPL snippets) written against
-# new JAX — ``jax.shard_map(..., check_vma=False)`` — run unchanged.
-if not hasattr(jax, "shard_map"):
-    jax.shard_map = shard_map
-
-
-# ---------------------------------------------------------------------------
-# Pallas TPU compiler params
-# ---------------------------------------------------------------------------
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` on new JAX, ``pltpu.TPUCompilerParams`` on
-    old; kwargs (e.g. ``dimension_semantics``) are identical across both."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
-
-
-# ---------------------------------------------------------------------------
-# Pallas interpret auto-detection
-# ---------------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=1)
-def default_interpret() -> bool:
-    """True (interpreter) off-TPU, False (compiled) on TPU. Used as the
-    default for every kernel's ``interpret=None``."""
-    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:
-    return default_interpret() if interpret is None else bool(interpret)
+    """``None``: the interpreter off-TPU, compiled on TPU."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

@@ -21,7 +21,7 @@ Four entry points share two kernel bodies:
                                 phase of two LP'd layers into one kernel
                                 launch instead of two.
   decode_attention_paged      — single layer against a PAGED cache pool
-                                ([n_pages, page_size, Hkv, hd]): instead of
+                                ([n_pages, Hkv, page_size, hd]): instead of
                                 a contiguous ring, each grid row streams the
                                 pages its request owns, with the block
                                 table as a scalar-prefetch operand feeding
@@ -30,7 +30,7 @@ Four entry points share two kernel bodies:
                                 materialised).
   decode_attention_pair_paged — the paged LP pair: one launch for both
                                 halves of a stacked pair pool
-                                ([2, n_pages, page_size, Hkv, hd]); both
+                                ([2, n_pages, Hkv, page_size, hd]); both
                                 halves share ONE block table (an LP pair
                                 sits at the same stream position) and the
                                 leading pair axis folds into the page index
@@ -43,8 +43,11 @@ PER-ROW horizon ``t[b]`` (continuous batching: every slot sits at its own
 position) and an optional ``head_map`` (third scalar-prefetch operand)
 mapping local kv heads to stored pool heads, which is how replicated-kv TP
 ranks select their head in-kernel instead of deferring to the XLA gather
-path. ``interpret`` defaults to auto-detection (compiled on TPU,
-interpreter elsewhere — repro.compat).
+path. Pages are head-major (repro.model.attention.seq_to_pages), so one
+grid step streams one contiguous ``[page_size, hd]`` tile of one kv head:
+a tile the TPU lowering accepts as a block, read exactly once per row.
+``interpret`` defaults to auto-detection (compiled on TPU, interpreter
+elsewhere — repro.compat).
 """
 from __future__ import annotations
 
@@ -55,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import resolve_interpret, tpu_compiler_params
+from repro.compat import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -121,7 +124,7 @@ def _launch(qr, kr, vr, t_valid, *, block_l, interpret):
                             pltpu.VMEM((g,), jnp.float32),
                             pltpu.VMEM((g, hd), jnp.float32)],
         ),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(t_arr, qr, kr, vr)
@@ -174,8 +177,8 @@ def _paged_kernel(bt_ref, t_ref, hm_ref, q_ref, k_ref, v_ref, o_ref, m_sc,
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     q = q_ref[0].astype(jnp.float32)                 # [g, hd]
-    k = k_ref[0, :, 0].astype(jnp.float32)           # [ps, hd]
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0, 0].astype(jnp.float32)              # [ps, hd]
+    v = v_ref[0, 0].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -203,7 +206,7 @@ def _paged_kernel(bt_ref, t_ref, hm_ref, q_ref, k_ref, v_ref, o_ref, m_sc,
 def _launch_paged(qr, k_pages, v_pages, block_tables, t_valid, *, n_half,
                   B, hkv, head_map=None, interpret):
     """qr: [R, g, hd] flattened rows (R = nP*B*hkv, pair-major); k/v_pages:
-    [nP*n_half, ps, Hkv, hd] with the pair axis folded into the page axis;
+    [nP*n_half, Hkv, ps, hd] with the pair axis folded into the page axis;
     block_tables: [B, n_pg]; t_valid: [B]. The block table is a scalar-
     prefetch operand: the k/v index maps translate (row, page-step) ->
     physical page id, so each row streams exactly the pages its request
@@ -217,7 +220,7 @@ def _launch_paged(qr, k_pages, v_pages, block_tables, t_valid, *, n_half,
     per-rank kv gather is ever materialised (the selection the XLA path
     does with ``attention.select_local_kv``)."""
     R, g, hd = qr.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     n_pg = block_tables.shape[1]
     bt = jnp.asarray(block_tables, jnp.int32)
     t_arr = jnp.asarray(t_valid, jnp.int32).reshape(B)
@@ -229,7 +232,7 @@ def _launch_paged(qr, k_pages, v_pages, block_tables, t_valid, *, n_half,
         half = r // (B * hkv)            # 0 (single / first layer) or 1
         b = (r // hkv) % B
         h = r % hkv
-        return (half * n_half + bt_ref[b, j], 0, hm_ref[h], 0)
+        return (half * n_half + bt_ref[b, j], hm_ref[h], 0, 0)
 
     kern = functools.partial(_paged_kernel, ps=ps, n_pg=n_pg, B=B, hkv=hkv,
                              scale=hd ** -0.5)
@@ -241,15 +244,15 @@ def _launch_paged(qr, k_pages, v_pages, block_tables, t_valid, *, n_half,
             grid=(R, n_pg),
             in_specs=[pl.BlockSpec((1, g, hd),
                                    lambda r, j, bt, t, hm: (r, 0, 0)),
-                      pl.BlockSpec((1, ps, 1, hd), kv_index),
-                      pl.BlockSpec((1, ps, 1, hd), kv_index)],
+                      pl.BlockSpec((1, 1, ps, hd), kv_index),
+                      pl.BlockSpec((1, 1, ps, hd), kv_index)],
             out_specs=pl.BlockSpec((1, g, hd),
                                    lambda r, j, bt, t, hm: (r, 0, 0)),
             scratch_shapes=[pltpu.VMEM((g,), jnp.float32),
                             pltpu.VMEM((g,), jnp.float32),
                             pltpu.VMEM((g, hd), jnp.float32)],
         ),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(bt, t_arr, hm, qr, k_pages, v_pages)
@@ -258,7 +261,7 @@ def _launch_paged(qr, k_pages, v_pages, block_tables, t_valid, *, n_half,
 def decode_attention_paged(q, k_pages, v_pages, block_tables, t_valid, *,
                            head_map=None, interpret=None):
     """Paged decode attention, one layer. q: [B, Hkv, g, hd]; k_pages,
-    v_pages: [n_pages, page_size, Hkv, hd]; block_tables: [B, n_pg] int32;
+    v_pages: [n_pages, Hkv, page_size, hd]; block_tables: [B, n_pg] int32;
     t_valid: [B] int32 per-slot horizons; head_map: optional [Hkv] int32
     mapping q's local kv-head axis to stored pool heads (replicated-kv TP
     ranks — see _launch_paged). Returns [B, Hkv, g, hd]."""
@@ -274,7 +277,7 @@ def decode_attention_pair_paged(q, k_pages, v_pages, block_tables, t_valid,
                                 *, head_map=None, interpret=None):
     """Fused paged LP-pair decode: ONE launch for both halves.
 
-    q: [2, B, Hkv, g, hd]; k_pages, v_pages: [2, n_pages, page_size, Hkv,
+    q: [2, B, Hkv, g, hd]; k_pages, v_pages: [2, n_pages, Hkv, page_size,
     hd] (the stacked pair pool); block_tables: [B, n_pg] SHARED by both
     halves (an LP pair sits at the same stream position, so its two layers
     occupy the same page indices of their own half); t_valid: [B] int32;

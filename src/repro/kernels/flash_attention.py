@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import resolve_interpret, tpu_compiler_params
+from repro.compat import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -124,7 +124,7 @@ def flash_attention(q, k, v, *, kind="causal", window=0, chunk=0,
         scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(qp, kp, vp)

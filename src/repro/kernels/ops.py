@@ -60,7 +60,7 @@ def decode_attention_pair(q, k, v, t_valid, *, block_l=256):
 @jax.jit
 def decode_attention_paged(q, k_pages, v_pages, block_tables, t_valid,
                            head_map=None):
-    """Paged decode: q [B, Hkv, g, hd]; k/v_pages [n_pages, ps, Hkv, hd];
+    """Paged decode: q [B, Hkv, g, hd]; k/v_pages [n_pages, Hkv, ps, hd];
     block_tables [B, n_pg]; t_valid [B]; head_map optional [Hkv] local ->
     stored kv-head selection (replicated-kv TP) -> [B, Hkv, g, hd]."""
     return _decode_paged(q, k_pages, v_pages, block_tables, t_valid,
@@ -71,7 +71,7 @@ def decode_attention_paged(q, k_pages, v_pages, block_tables, t_valid,
 def decode_attention_pair_paged(q, k_pages, v_pages, block_tables, t_valid,
                                 head_map=None):
     """Fused paged LP-pair decode: q [2, B, Hkv, g, hd]; k/v_pages
-    [2, n_pages, ps, Hkv, hd]; one shared block table (and one optional
+    [2, n_pages, Hkv, ps, hd]; one shared block table (and one optional
     head_map) for both halves -> [2, B, Hkv, g, hd] in ONE kernel launch."""
     return _decode_pair_paged(q, k_pages, v_pages, block_tables, t_valid,
                               head_map=head_map)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import resolve_interpret, tpu_compiler_params
+from repro.compat import resolve_interpret
 
 
 def _combine(e1, e2):
@@ -83,7 +83,7 @@ def ssm_scan(a, b, h0, *, block_s=256, block_c=128, interpret=None):
         out_specs=(pl.BlockSpec((1, bs, bc, N), lambda bt, c, s: (bt, s, c, 0)),
                    pl.BlockSpec((1, bc, N), lambda bt, c, s: (bt, c, 0))),
         scratch_shapes=[pltpu.VMEM((bc, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(a, b, h0)

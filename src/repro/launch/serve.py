@@ -18,8 +18,10 @@ over the model axis, scheduling stays host-side):
         python -m repro.launch.serve --arch tinyllama-1.1b --eff-depth 20 \
         --continuous --mesh 1x2 --requests 16 --new-tokens 32
 
-In-container this runs the reduced config on CPU host devices; on a real
-slice the same shard_map programs run unchanged.
+Without ``--full-config`` this runs the reduced config, which suits the
+CPU; with it, the published widths, which need the chip. Weights are
+random bf16 from a fixed seed, made already sharded under a mesh. The
+persistent compile cache is on (``repro.launch.compile_cache``).
 """
 from __future__ import annotations
 
@@ -32,6 +34,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced_config
 from repro.core.lp import EMPTY_PLAN, plan_for_depth
+from repro.launch import compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.model import transformer as T
 from repro.parallel.context import ParallelContext
@@ -159,6 +162,7 @@ def main() -> None:
     if isinstance(args.bucket_sizes, str):      # default never went through
         args.bucket_sizes = _parse_buckets(args.bucket_sizes)
 
+    compile_cache.enable()
     cfg = get_config(args.arch)
     if not args.full_config:
         cfg = reduced_config(cfg)
@@ -166,7 +170,7 @@ def main() -> None:
             else EMPTY_PLAN)
     mesh, mesh_m = make_serving_mesh(args.mesh)
     ms = T.build_structure(cfg, plan=plan, tp=mesh_m)
-    params = T.init_params(ms, jax.random.PRNGKey(0))
+    params = T.init_params(ms, jax.random.PRNGKey(0), jnp.bfloat16, mesh=mesh)
     pc = ParallelContext()
 
     if args.continuous:

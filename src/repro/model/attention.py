@@ -502,15 +502,39 @@ def decode_attn_standard(p, xn, cache_k, cache_v, t, cfg, dims: AttnDims, pc,
     return output_proj(p, o, dims, pair=False), cache_k, cache_v
 
 
+def seq_to_pages(x, axis: int, page_size: int):
+    """Stored kv ``[..., L, H, hd]`` (L at ``axis``) -> the paged pool's
+    page layout ``[..., L / page_size, H, page_size, hd]``.
+
+    Pages are head-major inside: one (page, kv head) block is a contiguous
+    ``[page_size, hd]`` tile, which is what the paged decode kernels'
+    BlockSpecs stream (a TPU block's last two dims must be tile-aligned or
+    whole, so a ``[page_size, 1, hd]`` slice of a token-major page cannot
+    be a block). Every producer and consumer of pool pages converts through
+    this pair of functions.
+    """
+    s = x.shape
+    x = x.reshape(*s[:axis], s[axis] // page_size, page_size, *s[axis + 1:])
+    return jnp.swapaxes(x, axis + 1, axis + 2)
+
+
+def pages_to_seq(x, axis: int):
+    """Inverse of ``seq_to_pages``: pool pages ``[..., n_pg, H, ps, hd]``
+    (n_pg at ``axis``) -> stored kv ``[..., n_pg * ps, H, hd]``."""
+    x = jnp.swapaxes(x, axis + 1, axis + 2)
+    s = x.shape
+    return x.reshape(*s[:axis], s[axis] * s[axis + 1], *s[axis + 2:])
+
+
 def decode_attn_paged(p, xn, k_pages, v_pages, t, block_tables, cfg,
                       dims: AttnDims, pc, *, kind, pair: bool):
     """Decode against the PAGED cache pool (continuous batching).
 
-    pair=False: xn [B,1,D], k/v_pages [n_pages, ps, hkv_stored, hd].
-    pair=True (fused LP pair): xn [2,B,1,D], k/v_pages [2, n_pages, ps,
-    hkv_stored, hd] stacked-contiguous — both halves occupy the SAME page
-    indices of their own half, so one block table serves the pair and the
-    pair still costs ONE projection, ONE scatter per cache tensor, ONE
+    pair=False: xn [B,1,D], k/v_pages [n_pages, hkv_stored, ps, hd].
+    pair=True (fused LP pair): xn [2,B,1,D], k/v_pages [2, n_pages,
+    hkv_stored, ps, hd] stacked-contiguous — both halves occupy the SAME
+    page indices of their own half, so one block table serves the pair and
+    the pair still costs ONE projection, ONE scatter per cache tensor, ONE
     attention launch and ONE merged output projection.
 
     t: [B] int32 per-slot absolute positions (every slot decodes at its own
@@ -531,7 +555,8 @@ def decode_attn_paged(p, xn, k_pages, v_pages, t, block_tables, cfg,
     q, k, v = project_qkv(p, xn, cfg, dims, pc, positions=t[:, None],
                           kind=kind, pair=pair)
     page_ax = 1 if pair else 0
-    ps = k_pages.shape[page_ax + 1]
+    hkv_st = k_pages.shape[page_ax + 1]
+    ps = k_pages.shape[page_ax + 2]
     # Indirection: position t lives at (bt[b, t // ps], t % ps).
     page_of = jnp.take_along_axis(block_tables, (t // ps)[:, None],
                                   axis=1)[:, 0]
@@ -540,13 +565,13 @@ def decode_attn_paged(p, xn, k_pages, v_pages, t, block_tables, cfg,
     scale = dims.hd ** -0.5
 
     if pair:
-        hkv_st = k_pages.shape[3]
-        # New-token kv arrives pair-folded [B,1,2*hkv,hd]; unfold and write
-        # both halves' (page, offset) in ONE scatter per cache tensor.
-        k2 = k.reshape(B, 2, hkv_st, dims.hd).transpose(1, 0, 2, 3)
-        v2 = v.reshape(B, 2, hkv_st, dims.hd).transpose(1, 0, 2, 3)
-        k_pages = k_pages.at[:, page_of, off].set(k2.astype(k_pages.dtype))
-        v_pages = v_pages.at[:, page_of, off].set(v2.astype(v_pages.dtype))
+        # New-token kv arrives pair-folded [B,1,2*hkv,hd]. Indexing the
+        # pool at [:, page_of, :, off] yields [B, 2, hkv, hd] (the advanced
+        # axes lead), so both halves' slots land in ONE scatter per tensor.
+        k2 = k.reshape(B, 2, hkv_st, dims.hd).astype(k_pages.dtype)
+        v2 = v.reshape(B, 2, hkv_st, dims.hd).astype(v_pages.dtype)
+        k_pages = k_pages.at[:, page_of, :, off].set(k2)
+        v_pages = v_pages.at[:, page_of, :, off].set(v2)
         qh = q.reshape(B, 2, Hk, g, dims.hd)           # pair-major heads, S=1
         if _DECODE_IMPL == "pallas":
             from repro.kernels import ops as KOPS
@@ -558,11 +583,11 @@ def decode_attn_paged(p, xn, k_pages, v_pages, t, block_tables, cfg,
             return output_proj(p, o, dims, pair=True), k_pages, v_pages
         # XLA path: gather the slots' pages back into per-request sequences
         # ([2, B, L, hkv, hd], L = n_pg * ps) and run the ring core math.
-        kg = jnp.take(k_pages, block_tables, axis=1)
-        vg = jnp.take(v_pages, block_tables, axis=1)
-        L = kg.shape[2] * ps
-        ks = select_local_kv_pair(kg.reshape(2, B, L, hkv_st, dims.hd), dims, pc)
-        vs = select_local_kv_pair(vg.reshape(2, B, L, hkv_st, dims.hd), dims, pc)
+        kg = pages_to_seq(jnp.take(k_pages, block_tables, axis=1), 2)
+        vg = pages_to_seq(jnp.take(v_pages, block_tables, axis=1), 2)
+        L = kg.shape[2]
+        ks = select_local_kv_pair(kg, dims, pc)
+        vs = select_local_kv_pair(vg, dims, pc)
         s = jnp.einsum("bpngh,pbtnh->bpngt", qh.astype(jnp.float32),
                        ks.astype(jnp.float32)) * scale
         valid = jnp.arange(L)[None, :] <= t[:, None]   # per-slot horizon
@@ -572,9 +597,8 @@ def decode_attn_paged(p, xn, k_pages, v_pages, t, block_tables, cfg,
         o = o.astype(xn.dtype).reshape(B, 1, 2 * dims.hq, dims.hd)
         return output_proj(p, o, dims, pair=True), k_pages, v_pages
 
-    hkv_st = k_pages.shape[2]
-    k_pages = k_pages.at[page_of, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page_of, off].set(v[:, 0].astype(v_pages.dtype))
+    k_pages = k_pages.at[page_of, :, off].set(k[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[page_of, :, off].set(v[:, 0].astype(v_pages.dtype))
     qh = q.reshape(B, 1, Hk, g, dims.hd)
     if _DECODE_IMPL == "pallas":
         from repro.kernels import ops as KOPS
@@ -583,11 +607,11 @@ def decode_attn_paged(p, xn, k_pages, v_pages, t, block_tables, cfg,
             paged_head_map(dims, pc)).astype(xn.dtype)
         o = o.reshape(B, 1, dims.hq, dims.hd)
         return output_proj(p, o, dims, pair=False), k_pages, v_pages
-    kg = jnp.take(k_pages, block_tables, axis=0)
-    vg = jnp.take(v_pages, block_tables, axis=0)
-    L = kg.shape[1] * ps
-    ks = select_local_kv(kg.reshape(B, L, hkv_st, dims.hd), dims, pc)
-    vs = select_local_kv(vg.reshape(B, L, hkv_st, dims.hd), dims, pc)
+    kg = pages_to_seq(jnp.take(k_pages, block_tables, axis=0), 1)
+    vg = pages_to_seq(jnp.take(v_pages, block_tables, axis=0), 1)
+    L = kg.shape[1]
+    ks = select_local_kv(kg, dims, pc)
+    vs = select_local_kv(vg, dims, pc)
     s = jnp.einsum("bsngh,btnh->bngst", qh.astype(jnp.float32),
                    ks.astype(jnp.float32)) * scale
     valid = jnp.arange(L)[None, :] <= t[:, None]

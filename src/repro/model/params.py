@@ -9,11 +9,13 @@ so the three can never drift apart.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+import functools
+from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
@@ -36,22 +38,43 @@ def _leaves(tmpl):
     return jax.tree.flatten(tmpl, is_leaf=is_pd)
 
 
-def init_tree(tmpl, key, dtype=jnp.float32):
+def _draw(pd: PD, dt, key):
+    if pd.init == "zeros":
+        return jnp.zeros(pd.shape, dt)
+    if pd.init == "ones":
+        return jnp.ones(pd.shape, dt)
+    fan = pd.fan_in
+    if fan is None:
+        fan = pd.shape[-2] if len(pd.shape) >= 2 else pd.shape[-1]
+    std = fan ** -0.5
+
+    def normal(k, shape):
+        return (jax.random.normal(k, shape, jnp.float32) * std).astype(dt)
+
+    if dt == jnp.float32 or len(pd.shape) < 3:
+        return normal(key, pd.shape)
+    # A stacked leaf (scan segment / LP pairs) in a narrower dtype: draw one
+    # leading slice at a time, so its float32 draw never exists whole.
+    return jax.lax.map(lambda k: normal(k, pd.shape[1:]),
+                       jax.random.split(key, pd.shape[0]))
+
+
+@functools.lru_cache(maxsize=None)
+def _leaf_program(pd: PD, dt, sharding):
+    return jax.jit(functools.partial(_draw, pd, dt), out_shardings=sharding)
+
+
+def init_tree(tmpl, key, dtype=jnp.float32, mesh=None):
+    """Initialised arrays for a template, each leaf made by its own jitted
+    program directly in its dtype. With ``mesh`` every leaf is made already
+    sharded by its pspec, so no device ever holds a whole leaf it does not
+    own."""
     leaves, treedef = _leaves(tmpl)
     keys = jax.random.split(key, max(len(leaves), 1))
     out = []
     for pd, k in zip(leaves, keys):
-        dt = pd.dtype or dtype
-        if pd.init == "zeros":
-            out.append(jnp.zeros(pd.shape, dt))
-        elif pd.init == "ones":
-            out.append(jnp.ones(pd.shape, dt))
-        else:
-            fan = pd.fan_in
-            if fan is None:
-                fan = pd.shape[-2] if len(pd.shape) >= 2 else pd.shape[-1]
-            std = fan ** -0.5
-            out.append((jax.random.normal(k, pd.shape, jnp.float32) * std).astype(dt))
+        sh = NamedSharding(mesh, pd.pspec) if mesh is not None else None
+        out.append(_leaf_program(pd, jnp.dtype(pd.dtype or dtype), sh)(k))
     return treedef.unflatten(out)
 
 

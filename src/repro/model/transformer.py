@@ -122,9 +122,13 @@ def model_template(ms: ModelStructure) -> Dict[str, Any]:
     return t
 
 
-def init_params(ms: ModelStructure, key, dtype=jnp.float32) -> PyTree:
+def init_params(ms: ModelStructure, key, dtype=jnp.float32,
+                mesh=None) -> PyTree:
+    """Random parameters from ``key``. ``mesh``: make every leaf already
+    sharded by ``param_pspecs(ms)`` on it (serving under tp > 1)."""
     if not ms.fsdp:
-        return init_tree(model_template(ms), key, dtype)
+        return init_tree(model_template(ms), key, dtype, mesh=mesh)
+    assert mesh is None, "FSDP params are packed on the host"
     # FSDP: init the REGULAR template (correct fan-in scaling), then pack.
     from repro.parallel import fsdp as F
     reg = build_structure(ms.cfg, plan=ms.plan, tp=ms.tp)

@@ -91,7 +91,6 @@ from jax import lax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import lp as LP
 from repro.model import embedding as E
 from repro.model import transformer as T
@@ -852,9 +851,11 @@ class PagedEngine:
             c_abs, c_specs = PG.paged_cache_meta(
                 ms, n_slots=self.n_main, n_pages=psv.n_pages,
                 page_size=psv.page_size, dtype=psv.cache_dtype)
-            self.caches = jax.tree.map(
-                lambda a, sh: jax.device_put(jnp.zeros(a.shape, a.dtype), sh),
-                c_abs, _tree_shardings(mesh, c_specs))
+            # Made already sharded: no device holds the whole pool.
+            self.caches = jax.jit(
+                lambda: jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                                     c_abs),
+                out_shardings=_tree_shardings(mesh, c_specs))()
         else:
             self.caches = PG.init_paged_caches(
                 ms, n_slots=self.n_main, n_pages=psv.n_pages,
@@ -1112,7 +1113,7 @@ class PagedEngine:
                 _, c_specs = PG.paged_cache_meta(
                     self.ms, n_slots=self.n_main, n_pages=self.psv.n_pages,
                     page_size=self.psv.page_size, dtype=self.psv.cache_dtype)
-                wrapped = shard_map(PG.scrub_pages, mesh=self.mesh,
+                wrapped = jax.shard_map(PG.scrub_pages, mesh=self.mesh,
                                     in_specs=(c_specs, P(), P()),
                                     out_specs=c_specs, check_vma=False)
                 return jax.jit(wrapped, donate_argnums=(0,))
@@ -2054,7 +2055,7 @@ def make_sharded_serve_step(ms: T.ModelStructure, mesh, sv: ServeConfig,
         c_abs, c_specs = PG.paged_cache_meta(
             ms, n_slots=batch, n_pages=paged.n_pages,
             page_size=paged.page_size, dtype=paged.cache_dtype)
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             local, mesh=mesh,
             in_specs=(p_specs, c_specs, P(), P(), P(), P(), P()),
             out_specs=(P(), P(), c_specs),
@@ -2068,7 +2069,7 @@ def make_sharded_serve_step(ms: T.ModelStructure, mesh, sv: ServeConfig,
     dp = tuple(pc.dp_axes) if pc.dp_axes else (None,)
     dp_ax = (dp if len(dp) > 1 else dp[0]) if shard_batch else None
     tok_spec = P(dp_ax)
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(p_specs, tok_spec, c_specs, P(), P()),
         out_specs=(tok_spec, c_specs),
@@ -2118,7 +2119,7 @@ def make_sharded_prefill(ms: T.ModelStructure, mesh, sv: ServeConfig,
         _, c_specs = PG.paged_cache_meta(
             ms, n_slots=paged_slots or paged.n_slots, n_pages=paged.n_pages,
             page_size=paged.page_size, dtype=paged.cache_dtype)
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             local, mesh=mesh,
             in_specs=(p_specs, c_specs) + (P(),) * n_rep,
             out_specs=(P(), P(), c_specs),
@@ -2147,7 +2148,7 @@ def make_sharded_prefill(ms: T.ModelStructure, mesh, sv: ServeConfig,
             frames = extras[i]; i += 1
         return local(params, tokens, prefix, frames)
 
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         local_n, mesh=mesh,
         in_specs=tuple(in_specs),
         out_specs=(P(dp_ax, "model"), c_specs),

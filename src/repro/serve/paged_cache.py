@@ -9,10 +9,14 @@ exactly the pages its length needs and returns them the moment it finishes,
 so requests of very different lengths share one cache allocation.
 
 Layout: the pool keeps PR 1's stacked pair layout end to end. A fused LP
-pair's k/v pool is ``[2, n_pages, page_size, Hkv, hd]`` (leading pair axis,
-bare entry names), a per-layer entry is ``[n_pages, page_size, Hkv, hd]``
-(indexed names ``k0``/``v0``) — i.e. the ring layout with the ``[B, L]``
-prefix replaced by ``[n_pages, page_size]``. Both halves of a pair live at
+pair's k/v pool is ``[2, n_pages, Hkv, page_size, hd]`` (leading pair axis,
+bare entry names), a per-layer entry is ``[n_pages, Hkv, page_size, hd]``
+(indexed names ``k0``/``v0``) — the ring layout's ``[B, L, Hkv, hd]``
+tail cut into pages that are head-major inside, so one (page, head) block
+is a contiguous ``[page_size, hd]`` tile for the decode kernels.
+``attention.seq_to_pages`` / ``pages_to_seq`` are the only conversions
+between the two; every scatter and gather here goes through them. Both
+halves of a pair live at
 the SAME page indices of their own half of the leading axis, so one block
 table serves the pair and homogeneous pairs still stream through one kernel
 launch (``repro.kernels.decode_attention.decode_attention_pair_paged``).
@@ -32,11 +36,10 @@ them up front.
 
 Sharding (tp > 1): the pool shards over the model axis exactly like the
 ring cache — the stored kv-head axis is cut when kv heads are sharded
-(n_kv >= tp, so each rank's shard is ``[2, n_pages, page_size, Hkv/tp,
+(n_kv >= tp, so each rank's shard is ``[2, n_pages, Hkv/tp, page_size,
 hd]``), replicated when n_kv < tp (ranks select their head in-kernel).
-``paged_cache_meta`` inherits the pspecs from the ring meta verbatim:
-replacing the ``[B, L]`` prefix with ``[n_pages, page_size]`` keeps every
-sharded axis at the same position, so no new partition rules exist for
+``paged_cache_meta`` derives the pspecs from the ring meta by the same
+axis permutation as the shapes, so no new partition rules exist for
 paged serving. Page ids, block tables and slot indices are host-side and
 tp-agnostic — ``scatter_prefill``/decode writes run unchanged inside
 shard_map on each rank's local shard.
@@ -52,6 +55,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.model import blocks as B
 from repro.model import transformer as T
+from repro.model.attention import pages_to_seq, seq_to_pages
 
 PyTree = Any
 
@@ -117,8 +121,9 @@ def validate_paged_support(ms: T.ModelStructure, max_len: int) -> None:
 def paged_cache_meta(ms: T.ModelStructure, *, n_slots: int, n_pages: int,
                      page_size: int, dtype=jnp.bfloat16):
     """(abstract, pspec) trees for the paged pool, mirroring the ring cache
-    tree structure (same segment list, same entry names) with the ``[B, L]``
-    prefix of every paged entry replaced by ``[n_pages, page_size]``.
+    tree structure (same segment list, same entry names) with the ``[B, L,
+    H, hd]`` tail of every paged entry replaced by the page layout
+    ``[n_pages, H, page_size, hd]``.
 
     ``dtype`` plays the role of ``prefill``'s cache cast: every float entry
     of the ring meta (including the fp32 recurrent state) is stored at
@@ -134,9 +139,13 @@ def paged_cache_meta(ms: T.ModelStructure, *, n_slots: int, n_pages: int,
             ba = T.cache_batch_axis(name)  # [count, (2,) B, ...]
             dt = dtype if a.dtype in (jnp.float32, jnp.bfloat16) else a.dtype
             if is_paged_entry(name):
-                # [count, (2,) B, L, H, hd] -> [count, (2,) n_pages, ps, H, hd]
-                shape = (*a.shape[:ba], n_pages, page_size, *a.shape[ba + 2:])
+                # [count, (2,) B, L, H, hd] -> [count, (2,) n_pages, H, ps, hd]
+                H, hd = a.shape[ba + 2:]
+                shape = (*a.shape[:ba], n_pages, H, page_size, hd)
                 spec = list(seg_ps[name])
+                spec += [None] * (a.ndim - len(spec))
+                spec = spec[:ba] + [spec[ba], spec[ba + 2], spec[ba + 1],
+                                    spec[ba + 3]]
                 na[name] = jax.ShapeDtypeStruct(shape, dt)
                 np_[name] = P(*spec)
             else:
@@ -174,9 +183,8 @@ def gather_ctx(pool: List[Dict], page_ids) -> List[Dict]:
             assert is_paged_entry(name), (
                 f"{name}: prefix sharing requires attention-only caches")
             ba = T.cache_batch_axis(name)   # page axis of the pool entry
-            g = jnp.take(pv, page_ids, axis=ba)   # [.., n_pg, ps, H, hd]
-            g = g.reshape(*g.shape[:ba], -1, *g.shape[ba + 2:])
-            nseg[name] = jnp.expand_dims(g, ba)   # batch-1 at the B axis
+            g = jnp.take(pv, page_ids, axis=ba)   # [.., n_pg, H, ps, hd]
+            nseg[name] = jnp.expand_dims(pages_to_seq(g, ba), ba)
         out.append(nseg)
     return out
 
@@ -206,10 +214,10 @@ def gather_ctx_rows(pool: List[Dict], page_ids) -> List[Dict]:
             assert is_paged_entry(name), (
                 f"{name}: prefix sharing requires attention-only caches")
             ba = T.cache_batch_axis(name)   # page axis of the pool entry
-            # [.., rows, n_pg, ps, H, hd]: rows becomes the batch axis in
+            # [.., rows, n_pg, H, ps, hd]: rows becomes the batch axis in
             # place (no expand_dims — the row axis replaces batch-1).
             g = jnp.take(pv, page_ids, axis=ba)
-            nseg[name] = g.reshape(*g.shape[:ba + 1], -1, *g.shape[ba + 3:])
+            nseg[name] = pages_to_seq(g, ba + 1)
         out.append(nseg)
     return out
 
@@ -234,10 +242,7 @@ def scrub_pages(pool: List[Dict], page_ids, slot):
         for name, pv in seg.items():
             ba = T.cache_batch_axis(name)
             if is_paged_entry(name):
-                n_pg = page_ids.shape[0]
-                ps = pv.shape[ba + 1]
-                z = jnp.zeros((*pv.shape[:ba], n_pg, ps, *pv.shape[ba + 2:]),
-                              pv.dtype)
+                z = jnp.zeros((), pv.dtype)
                 if ba == 2:   # stacked pair entry [count, 2, n_pages, ...]
                     nseg[name] = pv.at[:, :, page_ids].set(z)
                 else:         # per-layer entry [count, n_pages, ...]
@@ -273,10 +278,9 @@ def scatter_prefill(pool: List[Dict], seq: List[Dict], page_ids, slot):
             sv = seq_seg[name]
             ba = T.cache_batch_axis(name)
             if is_paged_entry(name):
-                ps = pv.shape[ba + 1]
+                ps = pv.shape[ba + 2]
                 s = jnp.squeeze(sv, axis=ba)   # drop B=1 -> length at ba
-                s = s.reshape(*s.shape[:ba], n_pg, ps, *s.shape[ba + 1:])
-                s = s.astype(pv.dtype)
+                s = seq_to_pages(s, ba, ps).astype(pv.dtype)
                 if ba == 2:   # stacked pair entry [count, 2, n_pages, ...]
                     nseg[name] = pv.at[:, :, page_ids].set(s)
                 else:         # per-layer entry [count, n_pages, ...]
@@ -326,10 +330,10 @@ def scatter_prefill_rows(pool: List[Dict], seq: List[Dict], page_ids):
                 f"{name}: bucketed prefill requires attention-only caches")
             sv = seq_seg[name]
             ba = T.cache_batch_axis(name)            # rows at ba, len at ba+1
-            ps = pv.shape[ba + 1]
-            # Merge (rows, len) -> (rows * n_pg, ps): adjacent axes.
-            s = sv.reshape(*sv.shape[:ba], n_rows * n_pg, ps,
-                           *sv.shape[ba + 2:])
+            ps = pv.shape[ba + 2]
+            # [.., rows, n_pg, H, ps, hd] -> merge (rows, n_pg): adjacent.
+            s = seq_to_pages(sv, ba + 1, ps)
+            s = s.reshape(*s.shape[:ba], n_rows * n_pg, *s.shape[ba + 2:])
             mask = valid.reshape((1,) * ba + (n_rows * n_pg,)
                                  + (1,) * (s.ndim - ba - 1))
             s = jnp.where(mask, s, jnp.zeros((), s.dtype)).astype(pv.dtype)
@@ -363,13 +367,11 @@ def rewind_tokens(pool: List[Dict], page_ids, offsets):
         for name, pv in seg.items():
             ba = T.cache_batch_axis(name)
             if is_paged_entry(name):
-                n = page_ids.shape[0]
-                z = jnp.zeros((*pv.shape[:ba], n, *pv.shape[ba + 2:]),
-                              pv.dtype)
-                if ba == 2:   # stacked pair entry [count, 2, n_pages, ...]
-                    nseg[name] = pv.at[:, :, page_ids, offsets].set(z)
-                else:         # per-layer entry [count, n_pages, ...]
-                    nseg[name] = pv.at[:, page_ids, offsets].set(z)
+                z = jnp.zeros((), pv.dtype)
+                if ba == 2:   # stacked pair entry [count, 2, n_pages, H, ps, hd]
+                    nseg[name] = pv.at[:, :, page_ids, :, offsets].set(z)
+                else:         # per-layer entry [count, n_pages, H, ps, hd]
+                    nseg[name] = pv.at[:, page_ids, :, offsets].set(z)
             else:
                 nseg[name] = pv
         out.append(nseg)

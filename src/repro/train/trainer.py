@@ -498,8 +498,7 @@ def make_sharded_train_step(ms: T.ModelStructure, mesh, tc: TrainConfig,
     local = make_train_step(ms, pc, tc)
     s_specs = state_pspecs(ms, pc, tc)
     b_specs = batch_pspecs(pc, batch_abstract)
-    from repro.compat import shard_map
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         local, mesh=mesh,
         in_specs=(s_specs, b_specs),
         out_specs=(s_specs, {"loss": P(), "xent": P(), "grad_norm": P(),

@@ -18,9 +18,11 @@ def rand_tokens(key, batch, seq, vocab):
 
 
 def run_multidevice(code: str, n_devices: int = 8, timeout: int = 600) -> str:
-    """Run ``code`` in a subprocess with n host devices; return stdout.
-    Raises on nonzero exit."""
+    """Run ``code`` in a subprocess with n CPU host devices; return stdout.
+    Raises on nonzero exit. The child is pinned to the CPU: the test
+    process may hold the accelerator."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -128,7 +128,14 @@ def test_bucketed_engine_matches_one_shot_generate():
 def test_bucket_fn_matches_exact_fn_rowwise():
     """Program level: one [rows, bucket] launch with right-padded prompts
     and an inert pad row produces, per real row, the SAME first token and
-    the SAME page bits as the exact-length batch-1 program."""
+    the same page kv as the exact-length batch-1 program, to f32 rounding.
+
+    Bit equality is not the contract here: XLA:CPU picks the blocking of
+    the MLP's d_ff-wide matmuls (up and down projections) by the operand's
+    row count, so the kv of every layer after the first can differ between
+    a [rows, bucket] launch and a [1, L] launch in the last f32 bits (~1e-6
+    on O(1) values). Layer 0's kv, which no MLP feeds, still matches bit
+    for bit."""
     cfg, ms, params = _build()
     psv = _psv()
     ps = psv.page_size
@@ -172,16 +179,18 @@ def test_bucket_fn_matches_exact_fn_rowwise():
                     continue
                 ba = T.cache_batch_axis(name)
                 for pg in pages[i][:npg]:
-                    # Bit equality over the page's REAL positions (the
-                    # in-page position axis sits right after the pool's
-                    # page axis); the tail of a partial page holds junk
-                    # kv in the bucketed tree but is never unmasked
-                    # before decode overwrites it.
+                    # Agreement over the page's REAL positions (the pool
+                    # is [.., page, head, position, hd]); the tail of a
+                    # partial page holds junk kv in the bucketed tree but
+                    # is never unmasked before decode overwrites it.
                     n_real = min(ps, L - pages[i].index(pg) * ps)
-                    sl = (slice(None),) * ba + (pg, slice(0, n_real))
+                    sl = ((slice(None),) * ba
+                          + (pg, slice(None), slice(0, n_real)))
                     got = np.asarray(seg_b[name][sl])
                     want = np.asarray(seg_e[name][sl])
-                    assert (got == want).all(), name
+                    assert (got[0] == want[0]).all(), name     # layer 0
+                    np.testing.assert_allclose(got, want, rtol=1e-5,
+                                               atol=1e-5, err_msg=name)
 
 
 def test_bucket_ctx_fn_matches_suffix_and_exact_fn_rowwise():
@@ -255,7 +264,8 @@ def test_bucket_ctx_fn_matches_suffix_and_exact_fn_rowwise():
                 if not PG.is_paged_entry(name):
                     continue
                 ba = T.cache_batch_axis(name)
-                sl = (slice(None),) * ba + (pg, slice(0, n_real))
+                sl = ((slice(None),) * ba
+                      + (pg, slice(None), slice(0, n_real)))
                 got = np.asarray(seg_b[name][sl])
                 want = np.asarray(seg_r[name][sl])
                 assert (got == want).all(), (name, pg)

@@ -30,13 +30,14 @@ KEY = jax.random.PRNGKey(0)
 
 
 def _paginate(cache, page_size):
-    """Ring cache [2, B, L, H, hd] -> (pool [2, n_pages, ps, H, hd], block
+    """Ring cache [2, B, L, H, hd] -> (pool [2, n_pages, H, ps, hd], block
     tables [B, L/ps]): slot b's pages are contiguous, after a garbage page."""
     P2, B, L, H, hd = cache.shape
     n_pg = L // page_size
     pool = jnp.concatenate(
-        [jnp.zeros((P2, 1, page_size, H, hd), cache.dtype),   # garbage page 0
-         cache.reshape(P2, B * n_pg, page_size, H, hd)], axis=1)
+        [jnp.zeros((P2, 1, H, page_size, hd), cache.dtype),   # garbage page 0
+         A.seq_to_pages(cache.reshape(P2, B * L, H, hd), 1, page_size)],
+        axis=1)
     bt = 1 + jnp.arange(B * n_pg, dtype=jnp.int32).reshape(B, n_pg)
     return pool, bt
 
@@ -78,10 +79,10 @@ def test_paged_decode_matches_ring(pair):
         # The written slot must land at (bt[b, t//ps], t%ps) in the pool.
         pg, off = int(bt[b, int(t[b]) // ps]), int(t[b]) % ps
         if pair:
-            written = nk_p[:, pg, off]
+            written = nk_p[:, pg, :, off]
             expect = nk_r[:, 0, int(t[b])]
         else:
-            written = nk_p[pg, off]
+            written = nk_p[pg, :, off]
             expect = nk_r[0, int(t[b])]
         assert jnp.allclose(written, expect), b
 
@@ -126,16 +127,16 @@ def test_paged_pool_keeps_stacked_pair_layout():
     dims = ms.dims
     for seg in abs_:
         assert set(seg.keys()) == {"k", "v"}
-        # [count, 2, n_pages, page_size, Hkv, hd] — pair axis INSIDE, pages
-        # replace the [B, L] prefix.
-        assert seg["k"].shape[1:] == (2, 9, 8, dims.hkv_global, dims.hd)
+        # [count, 2, n_pages, Hkv, page_size, hd] — pair axis INSIDE,
+        # head-major pages replace the [B, L] prefix.
+        assert seg["k"].shape[1:] == (2, 9, dims.hkv_global, 8, dims.hd)
 
     ms0 = T.build_structure(cfg, plan=LPPlan(()), tp=1)
     abs0, _ = PG.paged_cache_meta(ms0, n_slots=2, n_pages=9, page_size=8,
                                   dtype=jnp.float32)
     for seg in abs0:
         assert set(seg.keys()) == {"k0", "v0"}
-        assert seg["k0"].shape[1:] == (9, 8, dims.hkv_global, dims.hd)
+        assert seg["k0"].shape[1:] == (9, dims.hkv_global, 8, dims.hd)
 
 
 @pytest.mark.parametrize("arch", ["recurrentgemma-9b", "whisper-medium",

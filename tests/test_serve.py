@@ -139,7 +139,6 @@ def test_vocab_parallel_sample_matches_gather_reference():
     out = run_multidevice(r"""
 import jax, jax.numpy as jnp, json
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 from repro.model import embedding as E
 from repro.parallel.context import make_context
 
@@ -151,7 +150,7 @@ key = jax.random.PRNGKey(7)
 logits = jax.random.normal(jax.random.PRNGKey(3), (B, V), jnp.float32) * 3.0
 temp = 0.7
 
-fn = shard_map(lambda lg: E.vocab_parallel_sample(lg, key, temp, pc),
+fn = jax.shard_map(lambda lg: E.vocab_parallel_sample(lg, key, temp, pc),
                mesh=mesh, in_specs=(P(None, "model"),), out_specs=P(None),
                check_vma=False)
 toks = jax.jit(fn)(logits)

@@ -112,7 +112,6 @@ def test_pipeline_parallel_matches_sequential():
     out = run_multidevice(r"""
 import jax, jax.numpy as jnp, json
 from jax.sharding import PartitionSpec as P
-import repro.compat  # installs the jax.shard_map alias on old JAX
 from repro.parallel.pp import pipeline_apply, stage_slice
 
 mesh = jax.make_mesh((4,), ("pipe",))
@@ -155,7 +154,6 @@ def test_sp_on_off_equal():
     out = run_multidevice(r"""
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-import repro.compat  # installs the jax.shard_map alias on old JAX
 from repro.configs import get_config, reduced_config
 from repro.core.lp import plan_range
 from repro.model import transformer as T

@@ -64,12 +64,12 @@ def test_sharded_pool_layout_and_pspecs():
     for seg_abs, seg_ps in zip(abs_, ps_):
         assert set(seg_abs.keys()) == {"k", "v"}
         for name in ("k", "v"):
-            # [count, 2, n_pages, ps, Hkv_global, hd]
-            assert seg_abs[name].shape[1:] == (2, 9, 8, dims.hkv_global,
+            # [count, 2, n_pages, Hkv_global, ps, hd]
+            assert seg_abs[name].shape[1:] == (2, 9, dims.hkv_global, 8,
                                                dims.hd)
             spec = tuple(seg_ps[name])
-            assert spec[4] == "model", spec        # head axis sharded
-            assert all(s is None for i, s in enumerate(spec) if i != 4)
+            assert spec[3] == "model", spec        # head axis sharded
+            assert all(s is None for i, s in enumerate(spec) if i != 3)
 
     # Replicated kv (tp > n_kv): pool replicated, no model axis anywhere.
     cfg_r = dataclasses.replace(cfg, n_kv_heads=2)
@@ -86,7 +86,7 @@ def test_paged_kernel_head_map_selects_stored_head():
     """head_map=[i] must equal running the identity kernel on the pool
     sliced to head i — the in-kernel form of select_local_kv."""
     B, n_pages, ps, Hkv, hd, n_pg = 2, 7, 8, 3, 16, 3
-    k = jax.random.normal(jax.random.fold_in(KEY, 1), (n_pages, ps, Hkv, hd))
+    k = jax.random.normal(jax.random.fold_in(KEY, 1), (n_pages, Hkv, ps, hd))
     v = jax.random.normal(jax.random.fold_in(KEY, 2), k.shape)
     q = jax.random.normal(jax.random.fold_in(KEY, 3), (B, 1, 4, hd))
     bt = jnp.array([[1, 2, 3], [4, 5, 6]], jnp.int32)
@@ -94,8 +94,7 @@ def test_paged_kernel_head_map_selects_stored_head():
     for h in range(Hkv):
         got = decode_attention_paged(q, k, v, bt, t,
                                      head_map=jnp.array([h], jnp.int32))
-        ref = decode_attention_paged(q, k[:, :, h:h + 1], v[:, :, h:h + 1],
-                                     bt, t)
+        ref = decode_attention_paged(q, k[:, h:h + 1], v[:, h:h + 1], bt, t)
         assert jnp.array_equal(got, ref), h
 
 
@@ -104,15 +103,14 @@ def test_paged_pair_kernel_head_map_matches_sliced_pool():
     (the per-head TP mode) permute heads exactly like pool gathering."""
     B, n_pages, ps, Hkv, hd, n_pg = 2, 5, 4, 2, 16, 2
     k = jax.random.normal(jax.random.fold_in(KEY, 4),
-                          (2, n_pages, ps, Hkv, hd))
+                          (2, n_pages, Hkv, ps, hd))
     v = jax.random.normal(jax.random.fold_in(KEY, 5), k.shape)
     q = jax.random.normal(jax.random.fold_in(KEY, 6), (2, B, 2, 1, hd))
     bt = jnp.array([[1, 2], [3, 4]], jnp.int32)
     t = jnp.array([5, 7], jnp.int32)
     hm = jnp.array([1, 0], jnp.int32)              # swap the two heads
     got = decode_attention_pair_paged(q, k, v, bt, t, head_map=hm)
-    ref = decode_attention_pair_paged(q, k[:, :, :, ::-1], v[:, :, :, ::-1],
-                                      bt, t)
+    ref = decode_attention_pair_paged(q, k[:, :, ::-1], v[:, :, ::-1], bt, t)
     assert jnp.array_equal(got, ref)
 
 

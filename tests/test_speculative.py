@@ -157,7 +157,8 @@ def test_rewind_tokens_zeroes_only_targeted_positions():
                 continue
             a = np.asarray(v)
             ba = T.cache_batch_axis(name)
-            moved = np.moveaxis(a, (ba, ba + 1), (0, 1)) if ba else a
+            # [page, offset, ...]: the pool is [.., n_pages, H, ps, hd].
+            moved = np.moveaxis(a, (ba, ba + 2), (0, 1))
             assert (moved[2, 1] == 0).all() and (moved[3, 0] == 0).all()
             assert (moved[2, 0] == 1).all() and (moved[1] == 1).all()
 
