@@ -154,10 +154,6 @@ def main() -> None:
                      help="(--continuous) retain spans/gauge series for "
                           "traces (--no-telemetry caps memory on long "
                           "soaks; counters and faults stay live)")
-    tel.add_argument("--profile-decode", action="store_true",
-                     help="(--continuous) bracket each decode launch in a "
-                          "jax.profiler StepTraceAnnotation (only useful "
-                          "under an active jax profiler session)")
     args = ap.parse_args()
     if isinstance(args.bucket_sizes, str):      # default never went through
         args.bucket_sizes = _parse_buckets(args.bucket_sizes)
@@ -192,9 +188,7 @@ def main() -> None:
                 enabled=args.degrade_delta, slots=deg_slots,
                 eff_depth=args.degrade_eff_depth),
             spec=SpecConfig(k=args.spec_k, delta=args.spec_delta),
-            telemetry_cfg=TelemetryConfig(
-                enabled=args.telemetry,
-                profile_decode=args.profile_decode))
+            telemetry_cfg=TelemetryConfig(enabled=args.telemetry))
         if args.trace_out and not args.telemetry:
             ap.error("--trace-out needs telemetry (drop --no-telemetry)")
         eng = PagedEngine(params, ms, psv, mesh=mesh)

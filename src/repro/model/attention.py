@@ -570,55 +570,64 @@ def decode_attn_paged(p, xn, k_pages, v_pages, t, block_tables, cfg,
         # axes lead), so both halves' slots land in ONE scatter per tensor.
         k2 = k.reshape(B, 2, hkv_st, dims.hd).astype(k_pages.dtype)
         v2 = v.reshape(B, 2, hkv_st, dims.hd).astype(v_pages.dtype)
-        k_pages = k_pages.at[:, page_of, :, off].set(k2)
-        v_pages = v_pages.at[:, page_of, :, off].set(v2)
+        with jax.named_scope("kv_write"):
+            k_pages = k_pages.at[:, page_of, :, off].set(k2)
+            v_pages = v_pages.at[:, page_of, :, off].set(v2)
         qh = q.reshape(B, 2, Hk, g, dims.hd)           # pair-major heads, S=1
         if _DECODE_IMPL == "pallas":
             from repro.kernels import ops as KOPS
             qp = qh.transpose(1, 0, 2, 3, 4)           # [2,B,Hk,g,hd]
-            o = KOPS.decode_attention_pair_paged(
-                qp, k_pages, v_pages, block_tables, t,
-                paged_head_map(dims, pc)).astype(xn.dtype)
+            with jax.named_scope("attn_core"):
+                o = KOPS.decode_attention_pair_paged(
+                    qp, k_pages, v_pages, block_tables, t,
+                    paged_head_map(dims, pc)).astype(xn.dtype)
             o = o.transpose(1, 0, 2, 3, 4).reshape(B, 1, 2 * dims.hq, dims.hd)
             return output_proj(p, o, dims, pair=True), k_pages, v_pages
         # XLA path: gather the slots' pages back into per-request sequences
         # ([2, B, L, hkv, hd], L = n_pg * ps) and run the ring core math.
-        kg = pages_to_seq(jnp.take(k_pages, block_tables, axis=1), 2)
-        vg = pages_to_seq(jnp.take(v_pages, block_tables, axis=1), 2)
-        L = kg.shape[2]
-        ks = select_local_kv_pair(kg, dims, pc)
-        vs = select_local_kv_pair(vg, dims, pc)
-        s = jnp.einsum("bpngh,pbtnh->bpngt", qh.astype(jnp.float32),
-                       ks.astype(jnp.float32)) * scale
-        valid = jnp.arange(L)[None, :] <= t[:, None]   # per-slot horizon
-        s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
-        pweights = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bpngt,pbtnh->bpngh", pweights, vs.astype(jnp.float32))
-        o = o.astype(xn.dtype).reshape(B, 1, 2 * dims.hq, dims.hd)
+        with jax.named_scope("attn_core"):
+            kg = pages_to_seq(jnp.take(k_pages, block_tables, axis=1), 2)
+            vg = pages_to_seq(jnp.take(v_pages, block_tables, axis=1), 2)
+            L = kg.shape[2]
+            ks = select_local_kv_pair(kg, dims, pc)
+            vs = select_local_kv_pair(vg, dims, pc)
+            s = jnp.einsum("bpngh,pbtnh->bpngt", qh.astype(jnp.float32),
+                           ks.astype(jnp.float32)) * scale
+            valid = jnp.arange(L)[None, :] <= t[:, None]   # per-slot horizon
+            s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
+            pweights = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("bpngt,pbtnh->bpngh", pweights,
+                           vs.astype(jnp.float32))
+            o = o.astype(xn.dtype).reshape(B, 1, 2 * dims.hq, dims.hd)
         return output_proj(p, o, dims, pair=True), k_pages, v_pages
 
-    k_pages = k_pages.at[page_of, :, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page_of, :, off].set(v[:, 0].astype(v_pages.dtype))
+    with jax.named_scope("kv_write"):
+        k_pages = k_pages.at[page_of, :, off].set(
+            k[:, 0].astype(k_pages.dtype))
+        v_pages = v_pages.at[page_of, :, off].set(
+            v[:, 0].astype(v_pages.dtype))
     qh = q.reshape(B, 1, Hk, g, dims.hd)
     if _DECODE_IMPL == "pallas":
         from repro.kernels import ops as KOPS
-        o = KOPS.decode_attention_paged(
-            qh[:, 0], k_pages, v_pages, block_tables, t,
-            paged_head_map(dims, pc)).astype(xn.dtype)
+        with jax.named_scope("attn_core"):
+            o = KOPS.decode_attention_paged(
+                qh[:, 0], k_pages, v_pages, block_tables, t,
+                paged_head_map(dims, pc)).astype(xn.dtype)
         o = o.reshape(B, 1, dims.hq, dims.hd)
         return output_proj(p, o, dims, pair=False), k_pages, v_pages
-    kg = pages_to_seq(jnp.take(k_pages, block_tables, axis=0), 1)
-    vg = pages_to_seq(jnp.take(v_pages, block_tables, axis=0), 1)
-    L = kg.shape[1]
-    ks = select_local_kv(kg, dims, pc)
-    vs = select_local_kv(vg, dims, pc)
-    s = jnp.einsum("bsngh,btnh->bngst", qh.astype(jnp.float32),
-                   ks.astype(jnp.float32)) * scale
-    valid = jnp.arange(L)[None, :] <= t[:, None]
-    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
-    pweights = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bngst,btnh->bsngh", pweights, vs.astype(jnp.float32))
-    o = o.astype(xn.dtype).reshape(B, 1, dims.hq, dims.hd)
+    with jax.named_scope("attn_core"):
+        kg = pages_to_seq(jnp.take(k_pages, block_tables, axis=0), 1)
+        vg = pages_to_seq(jnp.take(v_pages, block_tables, axis=0), 1)
+        L = kg.shape[1]
+        ks = select_local_kv(kg, dims, pc)
+        vs = select_local_kv(vg, dims, pc)
+        s = jnp.einsum("bsngh,btnh->bngst", qh.astype(jnp.float32),
+                       ks.astype(jnp.float32)) * scale
+        valid = jnp.arange(L)[None, :] <= t[:, None]
+        s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
+        pweights = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bngst,btnh->bsngh", pweights, vs.astype(jnp.float32))
+        o = o.astype(xn.dtype).reshape(B, 1, dims.hq, dims.hd)
     return output_proj(p, o, dims, pair=False), k_pages, v_pages
 
 

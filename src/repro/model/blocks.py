@@ -113,6 +113,12 @@ def _mixer_kinds(group: Group):
     return tuple(s.mixer for s in group.specs)
 
 
+def _mixer_scope(mixer: str) -> str:
+    """Named scope of a group's temporal-mixing phase (norm, mixer and
+    residual add): ``attn`` for every attention kind, else the mixer."""
+    return "attn" if mixer.startswith("attn") else mixer
+
+
 def pair_cache_stacked(group: Group) -> bool:
     """True when the group's decode caches use the stacked-contiguous pair
     layout ([2, ...], bare key names) and the fused pair decode path. Pairs
@@ -193,9 +199,10 @@ def attention_phase_full(gp, xn, cfg, dims, pc, *, group: Group, positions,
             # breaking bit-identity with the cold full-prompt forward.
             ks, vs = lax.optimization_barrier((ks, vs))
         qh = q.reshape(B, S, nP * Hk, g, dims.hd)
-        o = A.attention_core(qh, ks, vs, kind=kind, window=cfg.window,
-                             chunk=cfg.chunk, prefix_len=prefix_len,
-                             q0=q0, impl=attn_impl)
+        with jax.named_scope("attn_core"):
+            o = A.attention_core(qh, ks, vs, kind=kind, window=cfg.window,
+                                 chunk=cfg.chunk, prefix_len=prefix_len,
+                                 q0=q0, impl=attn_impl)
         o = o.reshape(B, S, nP * dims.hq, dims.hd)
         out = A.output_proj(p, o, dims, pair=group.pair)
         return out, _split_kv(k, v, dims, pair=group.pair)
@@ -436,51 +443,53 @@ def apply_group_full(gp, x, *, cfg, group: Group, dims, pc: ParallelContext,
     # so an LP pair's two per-path norms read ONE gathered tensor — half
     # the phase-entry wire bytes of gathering the stacked [2,...] inputs
     # (EXPERIMENTS.md §Perf iteration 2).
-    xg = pc.phase_in(x)
-    xn = _norm_inputs(gp, "ln1", xg, cfg, group)
-    if ctx_kv is not None and not mixer.startswith("attn"):
-        raise NotImplementedError(
-            "suffix prefill requires attention mixers (recurrent state "
-            "cannot resume from cached kv)")
-    if mixer.startswith("attn"):
-        out, kvs = attention_phase_full(gp, xn, cfg, dims, pc, group=group,
-                                        positions=positions,
-                                        prefix_len=prefix_len,
-                                        attn_impl=attn_impl,
-                                        ctx_kv=ctx_kv, q0=q0)
-        if emit_cache:
-            fks, fvs = [], []
-            for i, (k, v) in enumerate(kvs):
-                m = group.specs[i].mixer
-                ss = seq_sharded_kind(cfg, dims, m, kv_mode)
-                fk = fill_cache(k, max_len, mixer=m, cfg=cfg,
-                                seq_shard=ss, pc=pc, dims=dims)
-                fv = fill_cache(v, max_len, mixer=m, cfg=cfg,
-                                seq_shard=ss, pc=pc, dims=dims)
-                if pair_cache_stacked(group):
-                    fks.append(fk)
-                    fvs.append(fv)
-                else:
-                    cache[f"k{i}"], cache[f"v{i}"] = fk, fv
-            if fks:  # stacked-contiguous pair layout for the fused decode
-                cache["k"] = jnp.stack(fks)
-                cache["v"] = jnp.stack(fvs)
-    else:
-        xn_p = xn if group.pair else xn[None]
-        key = "mamba" if mixer == "mamba" else "rec"
-        mp = gp[key] if group.pair else jax.tree.map(lambda w: w[None], gp[key])
-        if mixer == "mamba":
-            out, state = SSM.ssm_mix(mp, xn_p, cfg, pc, impl=scan_impl)
+    with jax.named_scope(_mixer_scope(mixer)):
+        xg = pc.phase_in(x)
+        xn = _norm_inputs(gp, "ln1", xg, cfg, group)
+        if ctx_kv is not None and not mixer.startswith("attn"):
+            raise NotImplementedError(
+                "suffix prefill requires attention mixers (recurrent state "
+                "cannot resume from cached kv)")
+        if mixer.startswith("attn"):
+            out, kvs = attention_phase_full(gp, xn, cfg, dims, pc, group=group,
+                                            positions=positions,
+                                            prefix_len=prefix_len,
+                                            attn_impl=attn_impl,
+                                            ctx_kv=ctx_kv, q0=q0)
+            if emit_cache:
+                fks, fvs = [], []
+                for i, (k, v) in enumerate(kvs):
+                    m = group.specs[i].mixer
+                    ss = seq_sharded_kind(cfg, dims, m, kv_mode)
+                    fk = fill_cache(k, max_len, mixer=m, cfg=cfg,
+                                    seq_shard=ss, pc=pc, dims=dims)
+                    fv = fill_cache(v, max_len, mixer=m, cfg=cfg,
+                                    seq_shard=ss, pc=pc, dims=dims)
+                    if pair_cache_stacked(group):
+                        fks.append(fk)
+                        fvs.append(fv)
+                    else:
+                        cache[f"k{i}"], cache[f"v{i}"] = fk, fv
+                if fks:  # stacked-contiguous pair layout for the fused decode
+                    cache["k"] = jnp.stack(fks)
+                    cache["v"] = jnp.stack(fvs)
         else:
-            out, state = RG.rglru_mix(mp, xn_p, cfg, pc, impl=scan_impl)
-        if emit_cache:
-            conv, h = state
-            if pair_cache_stacked(group):  # already stacked [2, ...]
-                cache["conv"], cache["h"] = conv, h
+            xn_p = xn if group.pair else xn[None]
+            key = "mamba" if mixer == "mamba" else "rec"
+            mp = (gp[key] if group.pair
+                  else jax.tree.map(lambda w: w[None], gp[key]))
+            if mixer == "mamba":
+                out, state = SSM.ssm_mix(mp, xn_p, cfg, pc, impl=scan_impl)
             else:
-                for i in range(nP):
-                    cache[f"conv{i}"], cache[f"h{i}"] = conv[i], h[i]
-    x = x + pc.phase_out(out).astype(x.dtype)
+                out, state = RG.rglru_mix(mp, xn_p, cfg, pc, impl=scan_impl)
+            if emit_cache:
+                conv, h = state
+                if pair_cache_stacked(group):  # already stacked [2, ...]
+                    cache["conv"], cache["h"] = conv, h
+                else:
+                    for i in range(nP):
+                        cache[f"conv{i}"], cache[f"h{i}"] = conv[i], h[i]
+        x = x + pc.phase_out(out).astype(x.dtype)
 
     # ---- cross-attention phase (whisper decoder) ----------------------
     if group.specs[0].cross_attn and enc_out is not None:
@@ -503,10 +512,11 @@ def apply_group_full(gp, x, *, cfg, group: Group, dims, pc: ParallelContext,
 
     # ---- phase 2: FFN ---------------------------------------------------
     if group.specs[0].ffn is not None:
-        xn2 = _norm_inputs(gp, "ln2", pc.phase_in(x), cfg, group)
-        out, a = ffn_phase(gp, xn2, cfg, pc, group=group)
-        aux = aux + a
-        x = x + pc.phase_out(out).astype(x.dtype)
+        with jax.named_scope("mlp"):
+            xn2 = _norm_inputs(gp, "ln2", pc.phase_in(x), cfg, group)
+            out, a = ffn_phase(gp, xn2, cfg, pc, group=group)
+            aux = aux + a
+            x = x + pc.phase_out(out).astype(x.dtype)
     return x, aux, cache
 
 
@@ -552,63 +562,70 @@ def apply_group_decode(gp, x, cache, t, *, cfg, group: Group, dims,
             "paged decode requires the stacked pair cache layout "
             "(heterogeneous attention pairs are not pageable)")
 
-    xn = _norm_inputs(gp, "ln1", x, cfg, group)
-    if mixer.startswith("attn"):
-        if paged and fused:
-            out, nk, nv = A.decode_attn_paged(
-                gp["attn"], xn, cache["k"], cache["v"], t, block_tables,
-                cfg, dims, pc, kind=mixer, pair=True)
-            new_cache["k"], new_cache["v"] = nk, nv
-        elif paged:
-            o, nk, nv = A.decode_attn_paged(
-                gp["attn"], xn, cache["k0"], cache["v0"], t, block_tables,
-                cfg, dims, pc, kind=mixer, pair=False)
-            out = o
-            new_cache["k0"], new_cache["v0"] = nk, nv
-        elif fused:
-            decode_fn = (A.decode_attn_seq_sharded
-                         if seq_sharded_kind(cfg, dims, mixer, kv_mode)
-                         else A.decode_attn_standard)
-            out, nk, nv = decode_fn(
-                gp["attn"], xn, cache["k"], cache["v"], t, cfg, dims, pc,
-                kind=mixer, pair=True, window=cfg.window, chunk=cfg.chunk)
-            new_cache["k"], new_cache["v"] = nk, nv
+    with jax.named_scope(_mixer_scope(mixer)):
+        xn = _norm_inputs(gp, "ln1", x, cfg, group)
+        if mixer.startswith("attn"):
+            if paged and fused:
+                out, nk, nv = A.decode_attn_paged(
+                    gp["attn"], xn, cache["k"], cache["v"], t, block_tables,
+                    cfg, dims, pc, kind=mixer, pair=True)
+                new_cache["k"], new_cache["v"] = nk, nv
+            elif paged:
+                o, nk, nv = A.decode_attn_paged(
+                    gp["attn"], xn, cache["k0"], cache["v0"], t, block_tables,
+                    cfg, dims, pc, kind=mixer, pair=False)
+                out = o
+                new_cache["k0"], new_cache["v0"] = nk, nv
+            elif fused:
+                decode_fn = (A.decode_attn_seq_sharded
+                             if seq_sharded_kind(cfg, dims, mixer, kv_mode)
+                             else A.decode_attn_standard)
+                out, nk, nv = decode_fn(
+                    gp["attn"], xn, cache["k"], cache["v"], t, cfg, dims, pc,
+                    kind=mixer, pair=True, window=cfg.window, chunk=cfg.chunk)
+                new_cache["k"], new_cache["v"] = nk, nv
+            else:
+                outs = []
+                for i, spec in enumerate(group.specs):
+                    ph = (jax.tree.map(lambda w: w[i], gp["attn"])
+                          if group.pair else gp["attn"])
+                    xi = xn[i] if group.pair else xn
+                    kd = spec.mixer
+                    if seq_sharded_kind(cfg, dims, kd, kv_mode):
+                        o, nk, nv = A.decode_attn_seq_sharded(
+                            ph, xi, cache[f"k{i}"], cache[f"v{i}"], t, cfg,
+                            dims, pc, kind=kd, pair=False, window=cfg.window,
+                            chunk=cfg.chunk)
+                    else:
+                        o, nk, nv = A.decode_attn_standard(
+                            ph, xi, cache[f"k{i}"], cache[f"v{i}"], t, cfg,
+                            dims, pc, kind=kd, pair=False, window=cfg.window,
+                            chunk=cfg.chunk)
+                    outs.append(o)
+                    new_cache[f"k{i}"], new_cache[f"v{i}"] = nk, nv
+                out = sum(outs)
         else:
-            outs = []
-            for i, spec in enumerate(group.specs):
-                ph = jax.tree.map(lambda w: w[i], gp["attn"]) if group.pair else gp["attn"]
-                xi = xn[i] if group.pair else xn
-                kd = spec.mixer
-                if seq_sharded_kind(cfg, dims, kd, kv_mode):
-                    o, nk, nv = A.decode_attn_seq_sharded(
-                        ph, xi, cache[f"k{i}"], cache[f"v{i}"], t, cfg, dims, pc,
-                        kind=kd, pair=False, window=cfg.window, chunk=cfg.chunk)
-                else:
-                    o, nk, nv = A.decode_attn_standard(
-                        ph, xi, cache[f"k{i}"], cache[f"v{i}"], t, cfg, dims, pc,
-                        kind=kd, pair=False, window=cfg.window, chunk=cfg.chunk)
-                outs.append(o)
-                new_cache[f"k{i}"], new_cache[f"v{i}"] = nk, nv
-            out = sum(outs)
-    else:
-        xn_p = xn if group.pair else xn[None]
-        key = "mamba" if mixer == "mamba" else "rec"
-        mp = gp[key] if group.pair else jax.tree.map(lambda w: w[None], gp[key])
-        if fused:  # stacked state: no per-step gather/scatter of the halves
-            conv, h = cache["conv"], cache["h"]
-        else:
-            conv = jnp.stack([cache[f"conv{i}"] for i in range(nP)], axis=0)
-            h = jnp.stack([cache[f"h{i}"] for i in range(nP)], axis=0)
-        if mixer == "mamba":
-            out, (nconv, nh) = SSM.ssm_mix(mp, xn_p, cfg, pc, state=(conv, h))
-        else:
-            out, (nconv, nh) = RG.rglru_mix(mp, xn_p, cfg, pc, state=(conv, h))
-        if fused:
-            new_cache["conv"], new_cache["h"] = nconv, nh
-        else:
-            for i in range(nP):
-                new_cache[f"conv{i}"], new_cache[f"h{i}"] = nconv[i], nh[i]
-    x = x + pc.psum_tp(out).astype(x.dtype)
+            xn_p = xn if group.pair else xn[None]
+            key = "mamba" if mixer == "mamba" else "rec"
+            mp = (gp[key] if group.pair
+                  else jax.tree.map(lambda w: w[None], gp[key]))
+            if fused:  # stacked state: no per-step gather/scatter of halves
+                conv, h = cache["conv"], cache["h"]
+            else:
+                conv = jnp.stack([cache[f"conv{i}"] for i in range(nP)], 0)
+                h = jnp.stack([cache[f"h{i}"] for i in range(nP)], axis=0)
+            if mixer == "mamba":
+                out, (nconv, nh) = SSM.ssm_mix(mp, xn_p, cfg, pc,
+                                               state=(conv, h))
+            else:
+                out, (nconv, nh) = RG.rglru_mix(mp, xn_p, cfg, pc,
+                                                state=(conv, h))
+            if fused:
+                new_cache["conv"], new_cache["h"] = nconv, nh
+            else:
+                for i in range(nP):
+                    new_cache[f"conv{i}"], new_cache[f"h{i}"] = nconv[i], nh[i]
+        x = x + pc.psum_tp(out).astype(x.dtype)
 
     if group.specs[0].cross_attn and ("xk" in cache or "xk0" in cache):
         xnx = _norm_inputs(gp, "lnx", x, cfg, group)
@@ -649,7 +666,8 @@ def apply_group_decode(gp, x, cache, t, *, cfg, group: Group, dims,
         x = x + pc.psum_tp(out).astype(x.dtype)
 
     if group.specs[0].ffn is not None:
-        xn2 = _norm_inputs(gp, "ln2", x, cfg, group)
-        out, _ = ffn_phase(gp, xn2, cfg, pc, group=group)
-        x = x + pc.psum_tp(out).astype(x.dtype)
+        with jax.named_scope("mlp"):
+            xn2 = _norm_inputs(gp, "ln2", x, cfg, group)
+            out, _ = ffn_phase(gp, xn2, cfg, pc, group=group)
+            x = x + pc.psum_tp(out).astype(x.dtype)
     return x, new_cache

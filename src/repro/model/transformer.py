@@ -190,8 +190,9 @@ def _embed(params, tokens, cfg, pc: ParallelContext, *, positions):
 
 def _head(params, x, cfg, pc: ParallelContext):
     """Final norm + column-parallel LM head -> LOCAL logits [..., V/tp]."""
-    x = apply_norm(x, params["final_norm"], cfg)
-    return E.local_logits(params["embed"], x, cfg, pc)
+    with jax.named_scope("head"):
+        x = apply_norm(x, params["final_norm"], cfg)
+        return E.local_logits(params["embed"], x, cfg, pc)
 
 
 def _encoder(params, frames, ms: ModelStructure, pc: ParallelContext,
@@ -259,12 +260,16 @@ def forward_full(params, tokens, *, ms: ModelStructure, pc: ParallelContext,
 
     x = pc.shard_seq(x)
     seg_params, gather_fns = stack_params_and_gathers(params, ms, pc)
-    x, aux, caches = ST.apply_stack_full(
-        seg_params, x, ms.segments, cfg=cfg, dims=ms.dims, pc=pc,
-        positions=positions, prefix_len=prefix_len, enc_out=enc_out,
-        attn_impl=attn_impl, emit_cache=emit_cache,
-        max_len=max_len or S, kv_mode=kv_mode, remat=remat,
-        scan_impl=scan_impl, gather_fns=gather_fns, ctx=ctx_kv, q0=start)
+    # The stack's own ops (slicing each layer's weights and cache out of
+    # the stacks, writing the cache back) are the ``layer_scan`` scope;
+    # the phases inside carry their own scopes.
+    with jax.named_scope("layer_scan"):
+        x, aux, caches = ST.apply_stack_full(
+            seg_params, x, ms.segments, cfg=cfg, dims=ms.dims, pc=pc,
+            positions=positions, prefix_len=prefix_len, enc_out=enc_out,
+            attn_impl=attn_impl, emit_cache=emit_cache,
+            max_len=max_len or S, kv_mode=kv_mode, remat=remat,
+            scan_impl=scan_impl, gather_fns=gather_fns, ctx=ctx_kv, q0=start)
     x = pc.phase_in(x)  # SP: re-gather the sequence before the LM head
     logits = _head(params, x, cfg, pc)
     return logits, aux, caches
@@ -354,9 +359,10 @@ def decode_step(params, tok, caches, t, *, ms: ModelStructure,
         pos = jnp.full((tok.shape[0], 1), t, jnp.int32)
     x = _embed(params, tok[:, None], cfg, dpc, positions=pos)
     seg_params, gather_fns = stack_params_and_gathers(params, ms, dpc)
-    x, new_caches = ST.apply_stack_decode(
-        seg_params, x, caches, t, ms.segments, cfg=cfg, dims=ms.dims,
-        pc=dpc, kv_mode=kv_mode, gather_fns=gather_fns,
-        cache_layout=cache_layout, block_tables=block_tables)
+    with jax.named_scope("layer_scan"):
+        x, new_caches = ST.apply_stack_decode(
+            seg_params, x, caches, t, ms.segments, cfg=cfg, dims=ms.dims,
+            pc=dpc, kv_mode=kv_mode, gather_fns=gather_fns,
+            cache_layout=cache_layout, block_tables=block_tables)
     logits = _head(params, x, cfg, dpc)
     return logits[:, 0], new_caches

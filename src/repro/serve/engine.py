@@ -78,7 +78,6 @@ runs — gated by the sharded-structural shared-prefix job.
 """
 from __future__ import annotations
 
-import contextlib
 import warnings
 from dataclasses import dataclass
 from functools import partial
@@ -108,7 +107,7 @@ from repro.serve.scheduler import (COHORT_DEGRADED, COHORT_MAIN,
                                    TERMINAL_STATES, PagePool, Request,
                                    Scheduler)
 from repro.serve.telemetry import (DECODE, PREFILL, REPLAY, ProgramCache,
-                                   Telemetry)
+                                   Telemetry, span)
 from repro.serve.trace import write_trace
 
 PyTree = Any
@@ -194,6 +193,20 @@ def generate(params, prompts, n_new: int, *, ms: T.ModelStructure,
 # Continuous batching over the paged pair-KV cache pool
 # ---------------------------------------------------------------------------
 
+def _named(fn, name: Optional[str]):
+    """``fn`` under ``name``, so that ``jax.jit`` names its module
+    ``jit_<name>`` and a profile can tell the engine's programs apart
+    (``telemetry.program_name``). None leaves ``fn`` as it is."""
+    if name is None:
+        return fn
+
+    def program(*args):
+        return fn(*args)
+
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def _finite_flag(pc: ParallelContext, *leaves) -> jnp.ndarray:
     """Scalar bool: every inexact leaf is fully finite (reduced over tp so
     all ranks agree — the host decision must be replicated)."""
@@ -202,6 +215,25 @@ def _finite_flag(pc: ParallelContext, *leaves) -> jnp.ndarray:
         if jnp.issubdtype(leaf.dtype, jnp.inexact):
             bad = bad | jnp.any(~jnp.isfinite(leaf)).astype(jnp.int32)
     return pc.pmax_tp(bad) == 0
+
+
+def _sample(logits, key, psv, pc: ParallelContext) -> jnp.ndarray:
+    """int32 token per row: greedy at temperature 0, else Gumbel-max,
+    vocab-parallel either way."""
+    if psv.temperature > 0:
+        nxt = E.vocab_parallel_sample(logits, key, psv.temperature, pc)
+    else:
+        nxt = E.vocab_parallel_argmax(logits, pc)
+    return nxt.astype(jnp.int32)
+
+
+def _cache_cast(seq, psv):
+    """Emitted prefill kv in the pool's dtype (the cast ``T.prefill``
+    applies to the ring cache), inside the ``kv_write`` scope."""
+    with jax.named_scope("kv_write"):
+        return jax.tree.map(
+            lambda c: c.astype(psv.cache_dtype)
+            if c.dtype in (jnp.float32, jnp.bfloat16) else c, seq)
 
 
 def make_paged_decode_fn(ms: T.ModelStructure, pc: ParallelContext, psv):
@@ -227,14 +259,12 @@ def make_paged_decode_fn(ms: T.ModelStructure, pc: ParallelContext, psv):
         logits, caches = T.decode_step(
             params, tok, caches, pos, ms=ms, pc=pc,
             cache_layout="paged", block_tables=bt)
-        logits = jnp.where(poison[:, None], jnp.nan, logits)
-        bad = jnp.any(~jnp.isfinite(logits), axis=-1).astype(jnp.int32)
-        ok = pc.pmax_tp(bad) == 0
-        if psv.temperature > 0:
-            nxt = E.vocab_parallel_sample(logits, key, psv.temperature, pc)
-        else:
-            nxt = E.vocab_parallel_argmax(logits, pc)
-        return nxt.astype(jnp.int32), ok, caches
+        with jax.named_scope("sample"):
+            logits = jnp.where(poison[:, None], jnp.nan, logits)
+            bad = jnp.any(~jnp.isfinite(logits), axis=-1).astype(jnp.int32)
+            ok = pc.pmax_tp(bad) == 0
+            nxt = _sample(logits, key, psv, pc)
+        return nxt, ok, caches
 
     return f
 
@@ -324,18 +354,14 @@ def make_paged_prefill_fn(ms: T.ModelStructure, pc: ParallelContext, psv,
             params, prompt, ms=ms, pc=pc, emit_cache=True,
             max_len=emit_len, kv_mode="heads",
             attn_impl=BK.PREFILL_ATTN_IMPL)
-        # Same cast T.prefill applies to the ring cache.
-        seq = jax.tree.map(
-            lambda c: c.astype(psv.cache_dtype)
-            if c.dtype in (jnp.float32, jnp.bfloat16) else c, seq)
-        last = logits[:, prompt_len - 1]
-        ok = _finite_flag(pc, last, *jax.tree.leaves(seq))
-        if psv.temperature > 0:
-            tok0 = E.vocab_parallel_sample(last, key, psv.temperature, pc)
-        else:
-            tok0 = E.vocab_parallel_argmax(last, pc)
-        caches = PG.scatter_prefill(caches, seq, page_ids, slot)
-        return tok0.astype(jnp.int32), ok, caches
+        seq = _cache_cast(seq, psv)
+        with jax.named_scope("sample"):
+            last = logits[:, prompt_len - 1]
+            ok = _finite_flag(pc, last, *jax.tree.leaves(seq))
+            tok0 = _sample(last, key, psv, pc)
+        with jax.named_scope("kv_write"):
+            caches = PG.scatter_prefill(caches, seq, page_ids, slot)
+        return tok0, ok, caches
 
     return f
 
@@ -378,7 +404,8 @@ def make_paged_bucket_prefill_fn(ms: T.ModelStructure, pc: ParallelContext,
     def f(params, caches, prompts, true_lens, page_ids, *rest):
         if ctx_pages:
             ctx_ids, ctx_lens, key = rest
-            ctx = PG.gather_ctx_rows(caches, ctx_ids)
+            with jax.named_scope("kv_gather"):
+                ctx = PG.gather_ctx_rows(caches, ctx_ids)
             start = ctx_lens
         else:
             (key,) = rest
@@ -388,26 +415,23 @@ def make_paged_bucket_prefill_fn(ms: T.ModelStructure, pc: ParallelContext,
             params, prompts, ms=ms, pc=pc, emit_cache=True,
             max_len=bucket, kv_mode="heads", ctx_kv=ctx, start=start,
             attn_impl=BK.PREFILL_ATTN_IMPL)
-        seq = jax.tree.map(
-            lambda c: c.astype(psv.cache_dtype)
-            if c.dtype in (jnp.float32, jnp.bfloat16) else c, seq)
-        last = jnp.take_along_axis(
-            logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
-        bad = jnp.any(~jnp.isfinite(last), axis=-1).astype(jnp.int32)
-        for seg in seq:
-            for name, c in seg.items():
-                if jnp.issubdtype(c.dtype, jnp.inexact):
-                    ba = T.cache_batch_axis(name)
-                    ax = tuple(i for i in range(c.ndim) if i != ba)
-                    bad = bad | jnp.any(~jnp.isfinite(c),
-                                        axis=ax).astype(jnp.int32)
-        ok = pc.pmax_tp(bad) == 0
-        if psv.temperature > 0:
-            tok0 = E.vocab_parallel_sample(last, key, psv.temperature, pc)
-        else:
-            tok0 = E.vocab_parallel_argmax(last, pc)
-        caches = PG.scatter_prefill_rows(caches, seq, page_ids)
-        return tok0.astype(jnp.int32), ok, caches
+        seq = _cache_cast(seq, psv)
+        with jax.named_scope("sample"):
+            last = jnp.take_along_axis(
+                logits, (true_lens - 1)[:, None, None], axis=1)[:, 0]
+            bad = jnp.any(~jnp.isfinite(last), axis=-1).astype(jnp.int32)
+            for seg in seq:
+                for name, c in seg.items():
+                    if jnp.issubdtype(c.dtype, jnp.inexact):
+                        ba = T.cache_batch_axis(name)
+                        ax = tuple(i for i in range(c.ndim) if i != ba)
+                        bad = bad | jnp.any(~jnp.isfinite(c),
+                                            axis=ax).astype(jnp.int32)
+            ok = pc.pmax_tp(bad) == 0
+            tok0 = _sample(last, key, psv, pc)
+        with jax.named_scope("kv_write"):
+            caches = PG.scatter_prefill_rows(caches, seq, page_ids)
+        return tok0, ok, caches
 
     return f
 
@@ -434,22 +458,20 @@ def make_paged_suffix_prefill_fn(ms: T.ModelStructure, pc: ParallelContext,
     emit_len = n_sfx * ps
 
     def f(params, caches, suffix, ctx_ids, sfx_ids, slot, key):
-        ctx = PG.gather_ctx(caches, ctx_ids)
+        with jax.named_scope("kv_gather"):
+            ctx = PG.gather_ctx(caches, ctx_ids)
         logits, _, seq = T.forward_full(
             params, suffix, ms=ms, pc=pc, emit_cache=True,
             max_len=emit_len, kv_mode="heads", ctx_kv=ctx, start=start,
             attn_impl=BK.PREFILL_ATTN_IMPL)
-        seq = jax.tree.map(
-            lambda c: c.astype(psv.cache_dtype)
-            if c.dtype in (jnp.float32, jnp.bfloat16) else c, seq)
-        last = logits[:, suffix_len - 1]
-        ok = _finite_flag(pc, last, *jax.tree.leaves(seq))
-        if psv.temperature > 0:
-            tok0 = E.vocab_parallel_sample(last, key, psv.temperature, pc)
-        else:
-            tok0 = E.vocab_parallel_argmax(last, pc)
-        caches = PG.scatter_prefill(caches, seq, sfx_ids, slot)
-        return tok0.astype(jnp.int32), ok, caches
+        seq = _cache_cast(seq, psv)
+        with jax.named_scope("sample"):
+            last = logits[:, suffix_len - 1]
+            ok = _finite_flag(pc, last, *jax.tree.leaves(seq))
+            tok0 = _sample(last, key, psv, pc)
+        with jax.named_scope("kv_write"):
+            caches = PG.scatter_prefill(caches, seq, sfx_ids, slot)
+        return tok0, ok, caches
 
     return f
 
@@ -486,9 +508,8 @@ class SpecConfig:
 
 @dataclass(frozen=True)
 class TelemetryConfig:
-    """Observability retention + profiling hooks."""
+    """Observability retention."""
     enabled: bool = True
-    profile_decode: bool = False
 
 
 @dataclass(frozen=True)
@@ -573,11 +594,8 @@ class PagedServeConfig:
     # soaks; counters, compile events and the fault log stay live (engine
     # semantics read them). Telemetry never adds device launches and never
     # changes outputs — the serve-structural gate runs a workload both ways
-    # and asserts bit-identity. profile_decode brackets each cohort's
-    # decode launch in a jax.profiler StepTraceAnnotation (needs an active
-    # jax.profiler trace to matter; off the hot path by default).
+    # and asserts bit-identity.
     telemetry: bool = True        # retain spans/gauge series/wall marks
-    profile_decode: bool = False  # jax.profiler annotation around decode
     # Bucketed prefill ladder: None = auto (powers-of-two page multiples
     # capped at max_len), () = off, explicit tuple = validated ladder.
     prefill_buckets: Optional[Tuple[int, ...]] = None
@@ -610,7 +628,6 @@ class PagedServeConfig:
             put("spec_delta", self.spec.delta)
         if self.telemetry_cfg is not None:
             put("telemetry", self.telemetry_cfg.enabled)
-            put("profile_decode", self.telemetry_cfg.profile_decode)
         if self.prefill_buckets is not None:
             put("prefill_buckets", tuple(self.prefill_buckets))
         # Canonical groups, rebuilt from the (possibly shimmed) flats.
@@ -623,8 +640,7 @@ class PagedServeConfig:
             queue_depth=self.degrade_queue_depth,
             eff_depth=self.degrade_eff_depth))
         put("spec", SpecConfig(k=self.spec_k, delta=self.spec_delta))
-        put("telemetry_cfg", TelemetryConfig(
-            enabled=self.telemetry, profile_decode=self.profile_decode))
+        put("telemetry_cfg", TelemetryConfig(enabled=self.telemetry))
 
     def validate(self, *, mesh: bool = False) -> None:
         """Every cross-field configuration rule, in one place. Actionable
@@ -905,9 +921,9 @@ class PagedEngine:
             # body is note()d so the compile stream still shows both.
             self._spec_step = self._programs.get(
                 SP.COHORT_SPEC_DRAFT, "decode", self.n_main,
-                lambda: jax.jit(
-                    make_spec_step_fn(self.ms_draft, ms, self.pc, psv,
-                                      self.spec_k),
+                lambda name: jax.jit(
+                    _named(make_spec_step_fn(self.ms_draft, ms, self.pc,
+                                             psv, self.spec_k), name),
                     donate_argnums=(2, 3)))
             self._programs.note(SP.COHORT_SPEC_VERIFY, "decode",
                                 self.n_main * (self.spec_k + 1))
@@ -994,19 +1010,21 @@ class PagedEngine:
     # Every builder below is compile-event-FREE: callers route through
     # ``self._programs.get(cohort, program, shape, build)``, which emits
     # the compile event exactly once per distinct key — the single
-    # compile-accounting increment site.
+    # compile-accounting increment site — and hands ``build`` the key's
+    # program name, which every build passes through ``_named``.
     def _make_decode(self, cohort: str):
         size = self.n_main if cohort == COHORT_MAIN else self.n_deg
 
-        def build():
+        def build(name):
             params_ms = self._model(cohort)[1] \
                 if cohort == COHORT_DEGRADED else self.ms
             if self.mesh is not None:
                 fn, _, _, _ = make_sharded_serve_step(
-                    params_ms, self.mesh, None, batch=size, paged=self.psv)
+                    params_ms, self.mesh, None, batch=size, paged=self.psv,
+                    name=name)
                 return fn
             local = make_paged_decode_fn(params_ms, self.pc, self.psv)
-            return jax.jit(local, donate_argnums=(1,))
+            return jax.jit(_named(local, name), donate_argnums=(1,))
 
         return self._programs.get(cohort, "decode", size, build)
 
@@ -1014,16 +1032,16 @@ class PagedEngine:
         """Exact-length prefill + page scatter, compiled once per distinct
         (prompt length, cohort) — the cohorts differ in both the model
         structure (re-paired stack) and the cache tree's slot count."""
-        def build():
+        def build(name):
             ms = self._model(cohort)[1]
             size = self.n_main if cohort == COHORT_MAIN else self.n_deg
             if self.mesh is not None:
                 fn, _, _ = make_sharded_prefill(
                     ms, self.mesh, None, batch=1, prompt_len=prompt_len,
-                    paged=self.psv, paged_slots=size)
+                    paged=self.psv, paged_slots=size, name=name)
                 return fn
             local = make_paged_prefill_fn(ms, self.pc, self.psv, prompt_len)
-            return jax.jit(local, donate_argnums=(1,))
+            return jax.jit(_named(local, name), donate_argnums=(1,))
 
         return self._programs.get(cohort, "prefill_full", prompt_len, build)
 
@@ -1051,24 +1069,24 @@ class PagedEngine:
         the same launch."""
         ctx_pages = self._bucket_ctx_pages(cohort)
 
-        def build():
+        def build(name):
             if self.mesh is not None:
                 fn, _, _ = make_sharded_prefill(
                     self.ms, self.mesh, None, batch=rows,
                     prompt_len=bucket, paged=self.psv,
                     paged_slots=self.n_main, bucket_rows=rows,
-                    bucket_ctx_pages=ctx_pages)
+                    bucket_ctx_pages=ctx_pages, name=name)
                 return fn
             ms = (self.ms_draft if cohort == SP.COHORT_SPEC_DRAFT
                   else self._model(cohort)[1])
             local = make_paged_bucket_prefill_fn(ms, self.pc, self.psv,
                                                  bucket, rows, ctx_pages)
-            return jax.jit(local, donate_argnums=(1,))
+            return jax.jit(_named(local, name), donate_argnums=(1,))
 
         return self._programs.get(cohort, "prefill_bucket", (bucket, rows),
                                   build)
 
-    def _suffix_fn(self, n_ctx_pages: int, suffix_len: int):
+    def _suffix_fn(self, n_ctx_pages: int, suffix_len: int, name: str):
         """Prefix-hit exact-shape prefill, compiled once per (context
         pages, suffix length) — the fallback when the suffix misses the
         bucket ladder. Main cohort only (the radix tree never holds
@@ -1078,11 +1096,11 @@ class PagedEngine:
             fn, _, _ = make_sharded_prefill(
                 self.ms, self.mesh, None, batch=1, prompt_len=suffix_len,
                 paged=self.psv, paged_slots=self.n_main,
-                suffix_ctx_pages=n_ctx_pages)
+                suffix_ctx_pages=n_ctx_pages, name=name)
             return fn
         local = make_paged_suffix_prefill_fn(self.ms, self.pc, self.psv,
                                              n_ctx_pages, suffix_len)
-        return jax.jit(local, donate_argnums=(1,))
+        return jax.jit(_named(local, name), donate_argnums=(1,))
 
     def _draft_decode_fn(self):
         """Single-step draft decode, compiled lazily — only the resume
@@ -1090,8 +1108,9 @@ class PagedEngine:
         ``_draft_episode`` program instead)."""
         return self._programs.get(
             SP.COHORT_SPEC_DRAFT, "decode_catchup", self.n_main,
-            lambda: jax.jit(
-                make_paged_decode_fn(self.ms_draft, self.pc, self.psv),
+            lambda name: jax.jit(
+                _named(make_paged_decode_fn(self.ms_draft, self.pc,
+                                            self.psv), name),
                 donate_argnums=(1,)))
 
     def _spec_prefill_fn(self, prompt_len: int):
@@ -1099,16 +1118,16 @@ class PagedEngine:
         distinct prompt length (tp=1 only — spec_k validation)."""
         return self._programs.get(
             SP.COHORT_SPEC_DRAFT, "prefill_full", prompt_len,
-            lambda: jax.jit(
-                make_paged_prefill_fn(self.ms_draft, self.pc, self.psv,
-                                      prompt_len),
+            lambda name: jax.jit(
+                _named(make_paged_prefill_fn(self.ms_draft, self.pc,
+                                             self.psv, prompt_len), name),
                 donate_argnums=(1,)))
 
     def _scrub_fn(self, cohort: str):
         """Compiled page/state scrub for one cohort (built lazily — the
         happy path never needs it). Fixed shapes: the page-id vector is
         padded with the garbage page."""
-        def build():
+        def build(name):
             if self.mesh is not None:
                 _, c_specs = PG.paged_cache_meta(
                     self.ms, n_slots=self.n_main, n_pages=self.psv.n_pages,
@@ -1116,8 +1135,8 @@ class PagedEngine:
                 wrapped = jax.shard_map(PG.scrub_pages, mesh=self.mesh,
                                     in_specs=(c_specs, P(), P()),
                                     out_specs=c_specs, check_vma=False)
-                return jax.jit(wrapped, donate_argnums=(0,))
-            return jax.jit(PG.scrub_pages, donate_argnums=(0,))
+                return jax.jit(_named(wrapped, name), donate_argnums=(0,))
+            return jax.jit(_named(PG.scrub_pages, name), donate_argnums=(0,))
 
         return self._programs.get(cohort, "scrub",
                                   self.psv.pages_per_slot, build)
@@ -1346,28 +1365,32 @@ class PagedEngine:
         caches = self._get_caches(cohort)
         params = self._model(cohort)[0]
         _, _, _, lo = self._arrays(cohort)
-        slot = jnp.int32(r.slot - lo)
-        self._key, sub = jax.random.split(self._key)
-        if ctx == 0:
-            fn = self._prefill_fn(Lp, cohort)
-            tok0, ok, caches = fn(
-                params, caches, jnp.asarray(r.prompt[None]),
-                jnp.asarray(r.pages[:n_pg_prompt], jnp.int32), slot, sub)
-            self.counters["prefill_tokens"] += Lp
-            self.counters["full_prefills"] += 1
-        else:
-            m = ctx // ps
-            Ls = Lp - ctx
-            fn = self._programs.get(COHORT_MAIN, "prefill_suffix", (m, Ls),
-                                    lambda: self._suffix_fn(m, Ls))
-            tok0, ok, caches = fn(
-                params, caches, jnp.asarray(r.prompt[None, ctx:]),
-                jnp.asarray(r.pages[:m], jnp.int32),
-                jnp.asarray(r.pages[m:n_pg_prompt], jnp.int32), slot, sub)
-            self.counters["prefill_tokens"] += Ls
-            self.counters["suffix_prefills"] += 1
-        self._set_caches(cohort, caches)
-        return int(tok0[0]), bool(ok)
+        with span("engine.prefill", cohort=cohort, bucket=Lp - ctx, rows=1):
+            slot = jnp.int32(r.slot - lo)
+            self._key, sub = jax.random.split(self._key)
+            if ctx == 0:
+                fn = self._prefill_fn(Lp, cohort)
+                tok0, ok, caches = fn(
+                    params, caches, jnp.asarray(r.prompt[None]),
+                    jnp.asarray(r.pages[:n_pg_prompt], jnp.int32), slot, sub)
+                self.counters["prefill_tokens"] += Lp
+                self.counters["full_prefills"] += 1
+            else:
+                m = ctx // ps
+                Ls = Lp - ctx
+                fn = self._programs.get(
+                    COHORT_MAIN, "prefill_suffix", (m, Ls),
+                    lambda name: self._suffix_fn(m, Ls, name))
+                tok0, ok, caches = fn(
+                    params, caches, jnp.asarray(r.prompt[None, ctx:]),
+                    jnp.asarray(r.pages[:m], jnp.int32),
+                    jnp.asarray(r.pages[m:n_pg_prompt], jnp.int32), slot,
+                    sub)
+                self.counters["prefill_tokens"] += Ls
+                self.counters["suffix_prefills"] += 1
+            self._set_caches(cohort, caches)
+            with span("engine.readback"):
+                return int(tok0[0]), bool(ok)
 
     def _replay(self, r: Request, start: int) -> bool:
         """Resume catch-up: teacher-force the parked generated tokens whose
@@ -1400,9 +1423,10 @@ class PagedEngine:
         self.telemetry.span_event(r.rid, REPLAY, self.step_count,
                                   tokens=end - start)
         caches = self._get_caches(cohort)
-        state_saved = [
-            {name: np.asarray(v) for name, v in seg.items()
-             if not PG.is_paged_entry(name)} for seg in caches]
+        with span("engine.readback"):
+            state_saved = [
+                {name: np.asarray(v) for name, v in seg.items()
+                 if not PG.is_paged_entry(name)} for seg in caches]
         no_poison = jnp.zeros((size,), jnp.bool_)
         survived = True
         for p in range(start, end):
@@ -1416,11 +1440,13 @@ class PagedEngine:
             nxt, ok, caches = decode(
                 params, caches, jnp.asarray(tok_v),
                 jnp.asarray(pos_v), jnp.asarray(bt), no_poison, sub)
-            if not bool(np.asarray(ok)[loc]):
+            with span("engine.readback"):
+                ok, nxt = np.asarray(ok), np.asarray(nxt)
+            if not bool(ok[loc]):
                 survived = False
                 break
             if self._exact:
-                got = int(np.asarray(nxt)[loc])
+                got = int(nxt[loc])
                 assert got == r.out[p - Lp + 1], (
                     f"replay divergence at pos {p}: {got} != "
                     f"{r.out[p - Lp + 1]} (rid={r.rid})")
@@ -1429,7 +1455,8 @@ class PagedEngine:
             for name, host in saved.items():
                 sl = (slice(None),) * T.cache_batch_axis(name) + (loc,)
                 merged = host.copy()
-                merged[sl] = np.asarray(seg[name])[sl]
+                with span("engine.readback"):
+                    merged[sl] = np.asarray(seg[name])[sl]
                 # Re-place at the entry's current sharding: under a mesh the
                 # state entries are model-sharded and a bare jnp.asarray
                 # would silently collapse them onto one device.
@@ -1641,47 +1668,50 @@ class PagedEngine:
         out: Dict[int, Tuple[int, bool]] = {}
         for i0 in range(0, len(grp), rows):
             chunk = grp[i0:i0 + rows]
-            prompts = np.zeros((rows, bucket), np.int32)
-            true_lens = np.ones((rows,), np.int32)
-            page_ids = np.full((rows, n_pg), PG.GARBAGE_PAGE, np.int32)
-            ctx_ids = np.full((rows, ctx_pages), PG.GARBAGE_PAGE, np.int32)
-            ctx_lens = np.zeros((rows,), np.int32)
-            for i, r in enumerate(chunk):
-                m = r.n_shared
-                Ls = r.prompt_len - m * ps
-                prompts[i, :Ls] = r.prompt[m * ps:]
-                true_lens[i] = Ls
-                npg = -(-r.prompt_len // ps)
-                page_ids[i, :npg - m] = r.pages[m:npg]
-                if m:
-                    assert ctx_pages, (cohort, m)
-                    ctx_ids[i, :m] = r.pages[:m]
-                    ctx_lens[i] = m * ps
-            self._key, sub = jax.random.split(self._key)
-            if draft_fn is not None:
-                hit = ctx_lens > 0
-                d_prompts = np.where(hit[:, None], 0, prompts)
-                d_lens = np.where(hit, 1, true_lens).astype(np.int32)
-                d_pages = np.where(hit[:, None], PG.GARBAGE_PAGE,
-                                   page_ids).astype(np.int32)
-                _, _, self.caches_draft = draft_fn(
-                    self.params_draft, self.caches_draft,
-                    jnp.asarray(d_prompts), jnp.asarray(d_lens),
-                    jnp.asarray(d_pages), sub)
-            args = [jnp.asarray(prompts), jnp.asarray(true_lens),
-                    jnp.asarray(page_ids)]
-            if ctx_pages:
-                args += [jnp.asarray(ctx_ids), jnp.asarray(ctx_lens)]
-            tok0, ok, caches = fn(
-                self._model(cohort)[0], caches, *args, sub)
-            tok0, ok = np.asarray(tok0), np.asarray(ok)
-            for i, r in enumerate(chunk):
-                out[r.rid] = (int(tok0[i]), bool(ok[i]))
-                if draft_fn is not None and not r.n_shared:
-                    self._spec_primed.add(r.rid)
-            self.counters["bucket_groups"] += 1
-            self.counters["pad_tokens"] += rows * bucket - sum(
-                r.prompt_len - r.n_shared * ps for r in chunk)
+            with span("engine.prefill", cohort=cohort, bucket=bucket,
+                      rows=rows):
+                prompts = np.zeros((rows, bucket), np.int32)
+                true_lens = np.ones((rows,), np.int32)
+                page_ids = np.full((rows, n_pg), PG.GARBAGE_PAGE, np.int32)
+                ctx_ids = np.full((rows, ctx_pages), PG.GARBAGE_PAGE, np.int32)
+                ctx_lens = np.zeros((rows,), np.int32)
+                for i, r in enumerate(chunk):
+                    m = r.n_shared
+                    Ls = r.prompt_len - m * ps
+                    prompts[i, :Ls] = r.prompt[m * ps:]
+                    true_lens[i] = Ls
+                    npg = -(-r.prompt_len // ps)
+                    page_ids[i, :npg - m] = r.pages[m:npg]
+                    if m:
+                        assert ctx_pages, (cohort, m)
+                        ctx_ids[i, :m] = r.pages[:m]
+                        ctx_lens[i] = m * ps
+                self._key, sub = jax.random.split(self._key)
+                if draft_fn is not None:
+                    hit = ctx_lens > 0
+                    d_prompts = np.where(hit[:, None], 0, prompts)
+                    d_lens = np.where(hit, 1, true_lens).astype(np.int32)
+                    d_pages = np.where(hit[:, None], PG.GARBAGE_PAGE,
+                                       page_ids).astype(np.int32)
+                    _, _, self.caches_draft = draft_fn(
+                        self.params_draft, self.caches_draft,
+                        jnp.asarray(d_prompts), jnp.asarray(d_lens),
+                        jnp.asarray(d_pages), sub)
+                args = [jnp.asarray(prompts), jnp.asarray(true_lens),
+                        jnp.asarray(page_ids)]
+                if ctx_pages:
+                    args += [jnp.asarray(ctx_ids), jnp.asarray(ctx_lens)]
+                tok0, ok, caches = fn(
+                    self._model(cohort)[0], caches, *args, sub)
+                with span("engine.readback"):
+                    tok0, ok = np.asarray(tok0), np.asarray(ok)
+                for i, r in enumerate(chunk):
+                    out[r.rid] = (int(tok0[i]), bool(ok[i]))
+                    if draft_fn is not None and not r.n_shared:
+                        self._spec_primed.add(r.rid)
+                self.counters["bucket_groups"] += 1
+                self.counters["pad_tokens"] += rows * bucket - sum(
+                    r.prompt_len - r.n_shared * ps for r in chunk)
         self._set_caches(cohort, caches)
         return out
 
@@ -1715,40 +1745,39 @@ class PagedEngine:
                    if lo <= s < lo + size}
         if not running:
             return
-        poison = np.zeros((size,), bool)
-        for s in self._poison_slots:
-            if lo <= s < lo + size:
-                poison[s - lo] = True
-        self._key, sub = jax.random.split(self._key)
-        prof = (jax.profiler.StepTraceAnnotation(
-                    f"paged_decode_{cohort}", step_num=self.step_count)
-                if self.psv.profile_decode else contextlib.nullcontext())
-        with prof:
+        with span("engine.decode", cohort=cohort):
+            poison = np.zeros((size,), bool)
+            for s in self._poison_slots:
+                if lo <= s < lo + size:
+                    poison[s - lo] = True
+            self._key, sub = jax.random.split(self._key)
             nxt, ok, caches = self._decode_fn(cohort)(
                 self._model(cohort)[0], self._get_caches(cohort),
                 jnp.asarray(tok_a), jnp.asarray(pos_a), jnp.asarray(bt_a),
                 jnp.asarray(poison), sub)
-        self._set_caches(cohort, caches)
-        nxt = np.asarray(nxt)
-        ok = np.asarray(ok)
-        for slot, r in sorted(running.items()):
-            loc = slot - lo
-            if not bool(ok[loc]):
-                # Non-finite logits on this row only: the decode step wrote
-                # this slot's kv from finite inputs EXCEPT possibly under
-                # real numeric poison, so scrub its private pages on the
-                # way out; every other row is untouched (row independence).
-                self._fail(r, NonFiniteLogitsError(
-                    f"rid={r.rid}: non-finite logits in decode at step "
-                    f"{self.step_count} (slot {slot})"),
-                    scrub=True)
-                continue
-            r.out.append(int(nxt[loc]))
-            tok_a[loc] = nxt[loc]
-            pos_a[loc] += 1
-            self.counters["decoded"] += 1
-            if r.done():
-                self._finish(r)
+            self._set_caches(cohort, caches)
+        with span("engine.readback"):
+            nxt = np.asarray(nxt)
+            ok = np.asarray(ok)
+        with span("engine.bookkeep"):
+            for slot, r in sorted(running.items()):
+                loc = slot - lo
+                if not bool(ok[loc]):
+                    # Non-finite logits on this row only: the decode step wrote
+                    # this slot's kv from finite inputs EXCEPT possibly under
+                    # real numeric poison, so scrub its private pages on the
+                    # way out; every other row is untouched (row independence).
+                    self._fail(r, NonFiniteLogitsError(
+                        f"rid={r.rid}: non-finite logits in decode at step "
+                        f"{self.step_count} (slot {slot})"),
+                        scrub=True)
+                    continue
+                r.out.append(int(nxt[loc]))
+                tok_a[loc] = nxt[loc]
+                pos_a[loc] += 1
+                self.counters["decoded"] += 1
+                if r.done():
+                    self._finish(r)
 
     def _rewind_pages(self, pairs: List[Tuple[int, int]]) -> None:
         """Un-write rejected speculative positions in BOTH cache trees.
@@ -1763,7 +1792,8 @@ class PagedEngine:
             pages[i], offs[i] = p, o
         rewind = self._programs.get(
             SP.COHORT_SPEC_VERIFY, "rewind", cap,
-            lambda: jax.jit(PG.rewind_tokens, donate_argnums=(0,)))
+            lambda name: jax.jit(_named(PG.rewind_tokens, name),
+                                 donate_argnums=(0,)))
         pg, of = jnp.asarray(pages), jnp.asarray(offs)
         self.caches = rewind(self.caches, pg, of)
         self.caches_draft = rewind(self.caches_draft, pg, of)
@@ -1801,21 +1831,19 @@ class PagedEngine:
         # twin of speculative.build_draft_step/build_verify_batch.
         # Draft rows are never poisoned — poison targets the slot's
         # COMMITTED stream, which only the verify rows can move.
-        self._key, sub = jax.random.split(self._key)
-        prof = (jax.profiler.StepTraceAnnotation(
-                    "paged_decode_spec_step", step_num=self.step_count)
-                if self.psv.profile_decode else contextlib.nullcontext())
-        with prof:
+        with span("engine.decode", cohort=SP.COHORT_SPEC_DRAFT):
+            self._key, sub = jax.random.split(self._key)
             d, yhat, ok, self.caches_draft, self.caches = self._spec_step(
                 self.params_draft, self.params, self.caches_draft,
                 self.caches, jnp.asarray(tok_a), jnp.asarray(pos_a),
                 jnp.asarray(bt_a), jnp.asarray(poison),
                 jnp.asarray(remaining.astype(np.int32)), sub)
-        drafts = np.asarray(d)
+        with span("engine.readback"):
+            drafts = np.asarray(d)
+            yhat = np.asarray(yhat).reshape(size, k + 1)
+            okm = np.asarray(ok).reshape(size, k + 1)
         self.counters["draft_steps"] += k
         self.counters["verify_steps"] += 1
-        yhat = np.asarray(yhat).reshape(size, k + 1)
-        okm = np.asarray(ok).reshape(size, k + 1)
         zero_pairs: List[Tuple[int, int]] = []
         for slot, r in sorted(running.items()):
             loc = slot - lo
@@ -1893,6 +1921,10 @@ class PagedEngine:
         telemetry counter DELTAS over the step, so there is exactly one
         increment site per event and the per-step view can never drift
         from the monotone totals."""
+        with span("engine.step", step=self.step_count):
+            return self._step()
+
+    def _step(self) -> Dict[str, int]:
         tel = self.telemetry
         before = {k: tel.counters[k] for k in self.STEP_STAT_KEYS}
         hit0 = tel.counters["hit_tokens"] + tel.counters["resume_hit_tokens"]
@@ -1900,13 +1932,15 @@ class PagedEngine:
         if self._plan is not None:
             self._inject()
         self._expire_pass()
-        self._admit(count_blocked=True)
-        if self.sched.should_preempt():
-            _victim, slot = self.sched.preempt_youngest(self.step_count)
-            self._clear_slot(slot)
-            # The freed pages/slot may unblock the head immediately.
-            self._admit(count_blocked=False)
-        self._validate_block_tables()
+        with span("engine.admit"):
+            self._admit(count_blocked=True)
+            if self.sched.should_preempt():
+                _victim, slot = self.sched.preempt_youngest(self.step_count)
+                self._clear_slot(slot)
+                # The freed pages/slot may unblock the head immediately.
+                self._admit(count_blocked=False)
+        with span("engine.bookkeep"):
+            self._validate_block_tables()
         if self.spec_k:
             self._decode_spec()
         else:
@@ -1914,10 +1948,11 @@ class PagedEngine:
         if self.n_deg:
             self._decode_cohort(COHORT_DEGRADED)
         self._poison_slots.clear()
-        self.pool.check_balance()
-        if self.prefix is not None:
-            self.prefix.check_locks()
-        self._step_gauges(hit0, faults0)
+        with span("engine.bookkeep"):
+            self.pool.check_balance()
+            if self.prefix is not None:
+                self.prefix.check_locks()
+            self._step_gauges(hit0, faults0)
         tel.mark_step(self.step_count)
         self.step_count += 1
         stats = {k: tel.counters[k] - before[k] for k in self.STEP_STAT_KEYS}
@@ -2034,7 +2069,8 @@ def cache_pspecs(ms: T.ModelStructure, *, batch: int, sv: ServeConfig,
 
 def make_sharded_serve_step(ms: T.ModelStructure, mesh, sv: ServeConfig,
                             *, batch: int, shard_batch: bool = True,
-                            paged: Optional[PagedServeConfig] = None):
+                            paged: Optional[PagedServeConfig] = None,
+                            name: Optional[str] = None):
     """jit(shard_map(serve_step)) + its in/out specs, for execution and the
     decode-shape dry-run.
 
@@ -2046,7 +2082,8 @@ def make_sharded_serve_step(ms: T.ModelStructure, mesh, sv: ServeConfig,
     replicated — host-side scheduling is tp-agnostic, so the ONLY sharded
     state is the pool itself; the finite flag ``ok`` is pmax-reduced over
     tp inside the step so its replicated out-spec holds. ``sv`` may be
-    None in that mode; ``batch`` is the slot count.
+    None in that mode; ``batch`` is the slot count. ``name``: the jitted
+    program's name (``_named``).
     """
     if paged is not None:
         pc = make_context(mesh, sp=False)
@@ -2060,7 +2097,8 @@ def make_sharded_serve_step(ms: T.ModelStructure, mesh, sv: ServeConfig,
             in_specs=(p_specs, c_specs, P(), P(), P(), P(), P()),
             out_specs=(P(), P(), c_specs),
             check_vma=False)
-        return jax.jit(wrapped, donate_argnums=(1,)), c_abs, c_specs, pc
+        return (jax.jit(_named(wrapped, name), donate_argnums=(1,)), c_abs,
+                c_specs, pc)
     pc = make_context(mesh, sp=False)
     local = make_serve_step(ms, pc, sv)
     p_specs = T.param_pspecs(ms)
@@ -2074,7 +2112,8 @@ def make_sharded_serve_step(ms: T.ModelStructure, mesh, sv: ServeConfig,
         in_specs=(p_specs, tok_spec, c_specs, P(), P()),
         out_specs=(tok_spec, c_specs),
         check_vma=False)
-    return jax.jit(wrapped, donate_argnums=(2,)), c_abs, c_specs, pc
+    return (jax.jit(_named(wrapped, name), donate_argnums=(2,)), c_abs,
+            c_specs, pc)
 
 
 def make_sharded_prefill(ms: T.ModelStructure, mesh, sv: ServeConfig,
@@ -2083,7 +2122,8 @@ def make_sharded_prefill(ms: T.ModelStructure, mesh, sv: ServeConfig,
                          paged_slots: Optional[int] = None,
                          bucket_rows: Optional[int] = None,
                          bucket_ctx_pages: int = 0,
-                         suffix_ctx_pages: Optional[int] = None):
+                         suffix_ctx_pages: Optional[int] = None,
+                         name: Optional[str] = None):
     """jit(shard_map(prefill)) for the ring cache (default), or — with
     ``paged`` — the engine's exact-length prefill + page scatter: the
     forward runs replicated over the sequence (sp off: prompt lengths are
@@ -2100,7 +2140,8 @@ def make_sharded_prefill(ms: T.ModelStructure, mesh, sv: ServeConfig,
     ``suffix_ctx_pages``: build the exact-shape SUFFIX prefill instead —
     ``prompt_len`` is the suffix length. Every non-tree operand is
     replicated (P()), so the spec count just follows the local program's
-    arity. Returns (fn, cache_pspecs, pc)."""
+    arity. ``name``: the jitted program's name (``_named``). Returns (fn,
+    cache_pspecs, pc)."""
     if paged is not None:
         pc = make_context(mesh, sp=False)
         if suffix_ctx_pages is not None:
@@ -2124,7 +2165,7 @@ def make_sharded_prefill(ms: T.ModelStructure, mesh, sv: ServeConfig,
             in_specs=(p_specs, c_specs) + (P(),) * n_rep,
             out_specs=(P(), P(), c_specs),
             check_vma=False)
-        return jax.jit(wrapped, donate_argnums=(1,)), c_specs, pc
+        return jax.jit(_named(wrapped, name), donate_argnums=(1,)), c_specs, pc
     pc = make_context(mesh, sp=sp)
     local = make_prefill(ms, pc, sv)
     p_specs = T.param_pspecs(ms)
@@ -2153,7 +2194,7 @@ def make_sharded_prefill(ms: T.ModelStructure, mesh, sv: ServeConfig,
         in_specs=tuple(in_specs),
         out_specs=(P(dp_ax, "model"), c_specs),
         check_vma=False)
-    return jax.jit(wrapped), c_specs, pc
+    return jax.jit(_named(wrapped, name)), c_specs, pc
 
 
 def make_sharded_generate(ms: T.ModelStructure, mesh, sv: ServeConfig,

@@ -52,15 +52,28 @@ and ``cohort`` (the degrade annotation); terminal transitions carry the
 transition is engine corruption, not a per-request fault.
 
 Zero-device-launch contract: nothing in this module (or in what the engine
-records into it) touches jax — it is pure host bookkeeping, appended
-outside the compiled programs. The serve-structural CI gate pins this:
-telemetry-on launch counts equal telemetry-off, telemetry-on greedy
+records into it) launches device work — it is pure host bookkeeping,
+appended outside the compiled programs. The serve-structural CI gate pins
+this: telemetry-on launch counts equal telemetry-off, telemetry-on greedy
 streams are bit-identical to telemetry-off, and same-seed chaos runs
 produce byte-identical wall-stripped traces. ``Telemetry(enabled=False)``
 additionally drops span/gauge-series/wall retention (counters, compile
 events, histograms and the fault log stay live — the engine's own
 ``stats``/replay machinery reads them), so long soaks can run without
 unbounded history growth.
+
+Profiler spans
+--------------
+``span`` is the engine's one way into a ``jax.profiler`` trace: host
+phases ``engine.step`` > ``engine.admit`` > ``engine.prefill`` (one per
+prefill launch) and ``engine.decode`` (one per cohort launch), with
+``engine.readback`` around every blocking read of a program's output and
+``engine.bookkeep`` around the host checks and the per-row token loop.
+They land on the clock the device's ops are recorded on, so a profile
+can say which phase the host was in while the device idled. With no
+profiler session running a span records nothing and costs well under a
+microsecond. ``program_name`` names each compiled program after its
+``ProgramCache`` key, so the device's ops can be told apart by program.
 """
 from __future__ import annotations
 
@@ -68,12 +81,14 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "SUBMITTED", "QUEUED", "ADMITTED", "PREFILL", "REPLAY", "DECODE",
     "PREEMPTED", "FINISHED", "FAILED", "CANCELLED", "EXPIRED",
     "SPAN_TERMINAL", "SPAN_TRANSITIONS", "DEFAULT_BUCKETS",
     "SpanStateError", "SpanEvent", "RequestSpan", "Histogram", "Telemetry",
-    "ProgramCache",
+    "ProgramCache", "program_name", "span",
 ]
 
 # Span states. The terminal four reuse the scheduler's status strings so a
@@ -218,6 +233,23 @@ class Histogram:
                 "count": self.count, "sum": self.sum}
 
 
+def span(name: str, **attrs) -> TraceAnnotation:
+    """A host span in a ``jax.profiler`` trace, with integer or string
+    ``attrs`` as its stats. Outside a profiler session it records nothing
+    and formats nothing (the name and attrs are only encoded while a
+    session runs)."""
+    return TraceAnnotation(name, **attrs)
+
+
+def program_name(cohort: str, program: str, shape) -> str:
+    """The name a compiled engine program carries into a profile: its
+    ``ProgramCache`` key as one identifier, program first
+    (``decode_main_12``, ``prefill_bucket_main_512x8``). JAX names the
+    compiled module ``jit_<name>``."""
+    dims = shape if isinstance(shape, tuple) else (shape,)
+    return f"{program}_{cohort}_{'x'.join(str(d) for d in dims)}"
+
+
 class ProgramCache:
     """ONE cache for every compiled serve program, keyed exactly like
     telemetry compile events: ``(cohort, program, shape)``.
@@ -238,13 +270,14 @@ class ProgramCache:
 
     def get(self, cohort: str, program: str, shape, build):
         """The compiled fn for the key, building (and recording the
-        compile event) on first use. ``build`` is a zero-arg callable
-        returning the jitted fn."""
+        compile event) on first use. ``build(name)`` returns the jitted
+        fn, named ``name`` (``program_name`` of the key), so the compile
+        event and the program in a profile carry the same key."""
         key = (cohort, program, shape)
         fn = self._programs.get(key)
         if fn is None:
             self._telemetry.compile_event(cohort, program, shape)
-            fn = self._programs[key] = build()
+            fn = self._programs[key] = build(program_name(*key))
         return fn
 
     def note(self, cohort: str, program: str, shape) -> None:

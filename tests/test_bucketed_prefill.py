@@ -407,13 +407,13 @@ def test_config_groups_are_views_over_flats():
                             prefill_token_budget=64, max_queue=3,
                             degrade_delta=True, degrade_slots=1,
                             degrade_queue_depth=2, degrade_eff_depth=2,
-                            telemetry=False, profile_decode=True)
+                            telemetry=False)
     grouped = PagedServeConfig(
         n_slots=4, page_size=8, n_pages=9, max_len=32,
         admission=AdmissionConfig(prefill_token_budget=64, max_queue=3),
         degrade=DegradeConfig(enabled=True, slots=1, queue_depth=2,
                               eff_depth=2),
-        telemetry_cfg=TelemetryConfig(enabled=False, profile_decode=True))
+        telemetry_cfg=TelemetryConfig(enabled=False))
     assert flat == grouped
     assert grouped.degrade_slots == 1 and grouped.max_queue == 3
     assert flat.admission == AdmissionConfig(prefill_token_budget=64,
@@ -452,13 +452,13 @@ def test_program_cache_single_increment_site():
     pc = ProgramCache(tel)
     built = []
 
-    def build():
-        built.append(1)
+    def build(name):
+        built.append(name)
         return "fn"
 
     assert pc.get("main", "decode", 4, build) == "fn"
     assert pc.get("main", "decode", 4, build) == "fn"
-    assert built == [1]                       # one build...
+    assert built == ["decode_main_4"]         # one build, named by its key...
     assert tel.compiles[("main", "decode", 4)] == 1   # ...one event
     assert ("main", "decode", 4) in pc and len(pc) == 1
     pc.note("spec_verify", "decode", 8)       # fused-program second body
