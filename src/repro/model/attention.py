@@ -31,6 +31,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -39,6 +40,8 @@ from repro.model.rope import apply_rope
 from repro.parallel.context import ParallelContext
 
 NEG_INF = -1e30
+#: Query tile of the chunked cores (``attention_core``'s ``qb``).
+Q_TILE = 512
 
 _DECODE_IMPL = "xla"
 
@@ -160,6 +163,36 @@ def _dense_core(q, k, v, mask):
     return o.astype(q.dtype)
 
 
+def _kv_tiles_upto(q_end, nk, *, kb, k0, kind, prefix_len, clip):
+    """Leading kv tiles (of ``nk``, ``kb`` wide, the first at key position
+    ``k0``) that the queries below absolute position ``q_end`` can see;
+    every later tile is masked for all of them. One formula for the host
+    count (``clip=np.clip``) and the traced loop bound (``jnp.clip``).
+    Window and chunk kinds only narrow the causal horizon, so the causal
+    bound holds for them; a prefix-LM prefix is visible to every query."""
+    if kind == "attn_bidir":
+        return nk
+    if prefix_len and kind in ("attn", "attn_global"):
+        q_end = clip(q_end, prefix_len, None)
+    return clip((q_end - k0 + kb - 1) // kb, 1, nk)
+
+
+def live_kv_tiles(S: int, T: int, qb: int, kb: int, q0_max: int, kind: str,
+                  prefix_len: int = 0) -> Tuple[int, int]:
+    """(kv tiles the pinned-tile core visits, tiles a full loop would
+    visit) for one attention core over ``S`` queries starting at
+    ``q0_max`` (the largest per-row offset) and ``T`` keys from position
+    0, with q tile ``qb`` and pinned kv tile ``kb`` — the host twin of
+    the core's traced bound, on Python ints."""
+    qb = min(qb, S)
+    nq, nk = -(-S // qb), -(-T // kb)
+    live = sum(int(_kv_tiles_upto(q0_max + (qi + 1) * qb, nk, kb=kb, k0=0,
+                                  kind=kind, prefix_len=prefix_len,
+                                  clip=np.clip))
+               for qi in range(nq))
+    return live, nq * nk
+
+
 def _chunked_core(q, k, v, *, kind, window, chunk, prefix_len, q0, k0,
                   qb: int, kb: int, fixed_kb: bool = False):
     """Online-softmax (flash-style) core: O(S*block) memory, scan over q and
@@ -177,7 +210,14 @@ def _chunked_core(q, k, v, *, kind, window, chunk, prefix_len, q0, k0,
     total padded length is. That makes right-padding the key axis BIT-
     TRANSPARENT for rows below the true length — the property the serve
     engine's bucketed prefill leans on (masked pad lanes carry finite
-    values, so ``0 * v`` is exactly 0).
+    values, so ``0 * v`` is exactly 0). The same no-op proof lets the
+    pinned loop stop early: q tile ``qi`` visits only the leading
+    ``_kv_tiles_upto`` tiles (every later one is masked for all of its
+    queries, and follows a tile each of its rows sees), so dead tiles —
+    causal ones, and the junk ctx columns the bucketed ctx-aware prefill
+    parks past each row's horizon — are skipped without changing a bit.
+    The unpinned path keeps a full ``lax.scan``: training differentiates
+    through it, and a traced trip count has no reverse-mode rule.
 
     ``q0`` may be an [B]-shaped array of PER-ROW offsets (the bucketed
     radix-suffix path: row i's queries start at its own ctx length); the
@@ -205,6 +245,7 @@ def _chunked_core(q, k, v, *, kind, window, chunk, prefix_len, q0, k0,
     vt = v.reshape(B, nk, kb, Hk, hd).transpose(1, 0, 3, 2, 4)
 
     per_row = getattr(q0, "ndim", 0) > 0   # q0 is [B]: per-row offsets
+    q0_max = jnp.max(q0) if per_row else q0
 
     def q_step(_, qi_and_tile):
         qi, qtile = qi_and_tile
@@ -234,7 +275,20 @@ def _chunked_core(q, k, v, *, kind, window, chunk, prefix_len, q0, k0,
         m0 = jnp.full((B, Hk, g, qb), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Hk, g, qb), jnp.float32)
         a0 = jnp.zeros((B, Hk, g, qb, hd), jnp.float32)
-        (m, l, acc), _ = lax.scan(kv_step, (m0, l0, a0), (jnp.arange(nk), kt, vt))
+        if fixed_kb:
+            n_live = _kv_tiles_upto(q0_max + (qi + 1) * qb, nk, kb=kb, k0=k0,
+                                    kind=kind, prefix_len=prefix_len,
+                                    clip=jnp.clip)
+
+            def live_step(ki, carry):
+                tiles = (lax.dynamic_index_in_dim(kt, ki, keepdims=False),
+                         lax.dynamic_index_in_dim(vt, ki, keepdims=False))
+                return kv_step(carry, (ki, *tiles))[0]
+
+            m, l, acc = lax.fori_loop(0, n_live, live_step, (m0, l0, a0))
+        else:
+            (m, l, acc), _ = lax.scan(kv_step, (m0, l0, a0),
+                                      (jnp.arange(nk), kt, vt))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return None, out.astype(q.dtype)
 
@@ -249,7 +303,7 @@ _PALLAS_KIND = {"attn": "causal", "attn_global": "causal",
 
 
 def attention_core(q, k, v, *, kind, window=0, chunk=0, prefix_len=0,
-                   q0=0, k0=0, impl="auto", qb=512, kb=1024):
+                   q0=0, k0=0, impl="auto", qb=Q_TILE, kb=1024):
     B, S, Hk, g, hd = q.shape
     T = k.shape[1]
     if getattr(q0, "ndim", 0) > 0 and not impl.startswith("chunked:"):

@@ -23,7 +23,11 @@ tile in a fori_loop, a fully-masked tile is an exact bitwise no-op
 reduces over the same ``kb`` lanes whatever the padded total is. Row p's
 output then depends ONLY on tiles covering [0, p] — padding on the right
 cannot move a single bit, and the batch dimension is bit-transparent by
-row independence. ``PREFILL_ATTN_IMPL`` names that impl; every prefill
+row independence. The same no-op proof is why the loop is allowed to stop
+at each q tile's last live tile: dead tiles (past the causal horizon of
+every query in the q tile, such as the pad columns and the junk ctx keys
+a cold row of the ctx-aware program carries) are not visited at all, and
+no bit moves. ``PREFILL_ATTN_IMPL`` names that impl; every prefill
 consumer (bucketed, exact, suffix, one-shot generate) must run it so the
 engine's streams and its ``generate()`` reference stay bitwise equal.
 
@@ -42,7 +46,8 @@ from typing import Optional, Tuple
 #: The prefill attention impl: flash-style chunked softmax with a PINNED
 #: 16-wide kv tile (see module docstring). 16 divides every page size the
 #: repo serves and keeps the fori_loop short at smoke scales.
-PREFILL_ATTN_IMPL = "chunked:16"
+PREFILL_KV_TILE = 16
+PREFILL_ATTN_IMPL = f"chunked:{PREFILL_KV_TILE}"
 
 
 def default_buckets(max_len: int, page_size: int) -> Tuple[int, ...]:

@@ -91,6 +91,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import lp as LP
+from repro.model import attention as A
 from repro.model import embedding as E
 from repro.model import transformer as T
 from repro.parallel.context import ParallelContext, make_context
@@ -846,6 +847,10 @@ class PagedEngine:
         self.prefix = (PrefixCache(psv.page_size, telemetry=self.telemetry)
                        if psv.prefix_cache and self._prefix_eligible(ms)
                        else None)
+        # Whether prefill runs an attention core at all (kv tile counts).
+        self._attends = any(spec.mixer.startswith("attn")
+                            for seg in ms.segments
+                            for spec in seg.group.specs)
         # Bucketed prefill needs the pinned-tile chunked impl's padding
         # transparency, which only the attention mixer family honours —
         # same eligibility gate as the prefix cache. None = auto ladder,
@@ -950,7 +955,7 @@ class PagedEngine:
         "submitted", "admitted", "decoded", "finished", "preempted",
         "failed", "expired", "cancelled", "shed", "degraded_admissions",
         "draft_steps", "verify_steps", "spec_accepted", "spec_rejected",
-        "spec_rewound")
+        "spec_rewound", "kv_tiles_live", "kv_tiles_total")
     #: The subset ``step()`` reports as per-step deltas.
     STEP_STAT_KEYS = ("admitted", "decoded", "finished", "preempted",
                       "failed", "expired")
@@ -1375,6 +1380,7 @@ class PagedEngine:
                     jnp.asarray(r.pages[:n_pg_prompt], jnp.int32), slot, sub)
                 self.counters["prefill_tokens"] += Lp
                 self.counters["full_prefills"] += 1
+                self._count_kv_tiles(cohort, Lp, Lp, 0)
             else:
                 m = ctx // ps
                 Ls = Lp - ctx
@@ -1388,6 +1394,7 @@ class PagedEngine:
                     sub)
                 self.counters["prefill_tokens"] += Ls
                 self.counters["suffix_prefills"] += 1
+                self._count_kv_tiles(cohort, Ls, ctx + Ls, ctx)
             self._set_caches(cohort, caches)
             with span("engine.readback"):
                 return int(tok0[0]), bool(ok)
@@ -1638,6 +1645,22 @@ class PagedEngine:
             pre.update(self._launch_bucket(cohort, b, grp))
         return pre
 
+    def _count_kv_tiles(self, cohort: str, S: int, T: int,
+                        q0_max: int) -> None:
+        """Add one main-cohort prefill launch's kv tiles to
+        ``kv_tiles_live`` (tiles one layer's attention core visits) and
+        ``kv_tiles_total`` (tiles a loop over every tile would visit):
+        ``S`` queries from ``q0_max`` (the largest row offset) over ``T``
+        keys. Paged models attend causally (``validate_paged_support``),
+        so the causal count is exact for each of their attention
+        layers."""
+        if cohort != COHORT_MAIN or not self._attends:
+            return
+        live, total = A.live_kv_tiles(S, T, A.Q_TILE, BK.PREFILL_KV_TILE,
+                                      q0_max, "attn")
+        self.counters["kv_tiles_live"] += live
+        self.counters["kv_tiles_total"] += total
+
     def _launch_bucket(self, cohort: str, bucket: int, grp: List[Request]
                        ) -> Dict[int, Tuple[int, bool]]:
         """One bucket group: right-pad each row's SUFFIX (the full prompt
@@ -1712,6 +1735,8 @@ class PagedEngine:
                 self.counters["bucket_groups"] += 1
                 self.counters["pad_tokens"] += rows * bucket - sum(
                     r.prompt_len - r.n_shared * ps for r in chunk)
+                self._count_kv_tiles(cohort, bucket, ctx_pages * ps + bucket,
+                                     int(ctx_lens.max()))
         self._set_caches(cohort, caches)
         return out
 
@@ -1889,13 +1914,20 @@ class PagedEngine:
             self._rewind_pages(zero_pairs)
             self.counters["spec_rewound"] += len(zero_pairs)
 
-    def _step_gauges(self, hit0: int, faults0: Dict[str, int]) -> None:
+    def _step_gauges(self, hit0: int, faults0: Dict[str, int],
+                     tiles0: Tuple[int, int]) -> None:
         """Per-step gauge samples, taken AFTER the step's work: queue
         depth, pool live/free/refcount-shared pages, per-step radix hit
-        tokens (fresh + resume), per-cohort slot occupancy, and faults by
-        kind (only steps where a kind fired emit a sample). All pure host
-        reads — no device work."""
+        tokens (fresh + resume), per-cohort slot occupancy, faults by
+        kind (only steps where a kind fired emit a sample), and the
+        prefill launches' live and total kv tiles (only steps that
+        launched one). All pure host reads — no device work."""
         tel, sc = self.telemetry, self.step_count
+        total = tel.counters["kv_tiles_total"] - tiles0[1]
+        if total:
+            tel.gauge("kv_tiles_live_step", sc,
+                      tel.counters["kv_tiles_live"] - tiles0[0])
+            tel.gauge("kv_tiles_total_step", sc, total)
         tel.gauge("queue_depth", sc, self.sched.n_queued)
         tel.gauge("pages_live", sc, self.pool.live)
         tel.gauge("pages_free", sc, self.pool.n_free)
@@ -1928,6 +1960,7 @@ class PagedEngine:
         tel = self.telemetry
         before = {k: tel.counters[k] for k in self.STEP_STAT_KEYS}
         hit0 = tel.counters["hit_tokens"] + tel.counters["resume_hit_tokens"]
+        tiles0 = (tel.counters["kv_tiles_live"], tel.counters["kv_tiles_total"])
         faults0 = dict(tel.fault_counts)
         if self._plan is not None:
             self._inject()
@@ -1952,7 +1985,7 @@ class PagedEngine:
             self.pool.check_balance()
             if self.prefix is not None:
                 self.prefix.check_locks()
-            self._step_gauges(hit0, faults0)
+            self._step_gauges(hit0, faults0, tiles0)
         tel.mark_step(self.step_count)
         self.step_count += 1
         stats = {k: tel.counters[k] - before[k] for k in self.STEP_STAT_KEYS}
