@@ -169,9 +169,15 @@ class Driver:
                 self.tracer is None or self.tracer.done)
             if closed and self.closed_at is None:
                 self.closed_at = self.we if self.tracer is None else clock()
+            if closed and now >= self.closed_at + self.drain:
+                break
+            # Submit before asking whether the window's requests are all
+            # done: a step that ran past the window's end (a compile) must
+            # not leave the requests due meanwhile unsubmitted and uncounted.
+            if not (closed and (backlog or self.tracer is not None)):
+                with _span(self.tracer, "bench.submit"):
+                    self._submit(now)
             if closed:
-                if now >= self.closed_at + self.drain:
-                    break
                 if backlog:
                     done = sum(r.done is not None and r.done > self.start
                                for r in self.recs.values())
@@ -179,9 +185,6 @@ class Driver:
                         break
                 elif all(r.done is not None for r in self.window_recs()):
                     break
-            if not (closed and (backlog or self.tracer is not None)):
-                with _span(self.tracer, "bench.submit"):
-                    self._submit(now)
             if eng.sched.n_queued or eng.sched.n_running:
                 self._step(clock)
                 continue

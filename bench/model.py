@@ -16,20 +16,20 @@ import dataclasses
 
 import jax.numpy as jnp
 
+import spec
 import weights as W
 
-#: Model keys of a configuration file and the program's ArchConfig field
-#: each must equal.
+#: Model keys of every configuration file and the program's ArchConfig
+#: field each must equal; each layer part checks the keys it reads.
 ARCH_FIELDS = {"d_model": "d_model", "n_layers": "n_layers",
-               "n_heads": "n_heads", "n_kv_heads": "n_kv_heads",
-               "head_dim": "head_dim", "d_ff": "d_ff",
-               "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-               "tie_embeddings": "tie_embeddings"}
+               "vocab_size": "vocab_size", "tie_embeddings": "tie_embeddings"}
 
 
 def arch_config(c: dict):
     """The program's ArchConfig for configuration ``c``, checked against
-    the configuration's model keys."""
+    the configuration's model keys: the shared ones, the layer pattern
+    (each layer's parts against the program's layer kinds) and the keys
+    each part relies on."""
     from repro.configs import get_config
     cfg = get_config(c["program_arch"])
     if c.get("program_overrides"):
@@ -37,8 +37,17 @@ def arch_config(c: dict):
     m = c["model"]
     bad = {k: (m[k], getattr(cfg, f)) for k, f in ARCH_FIELDS.items()
            if m[k] != getattr(cfg, f)}
-    if bad or cfg.norm_kind != "rmsnorm" or not cfg.mlp_gated \
-            or cfg.mlp_act != "silu" or cfg.pos_embed != "rope":
+    if cfg.norm_kind != "rmsnorm":
+        bad["norm_kind"] = ("rmsnorm", cfg.norm_kind)
+    for i, s in enumerate(cfg.layer_specs()):
+        kind = spec.layer_kind(m, i)
+        mixer, *ffn = spec.parts_of(kind)
+        want = (mixer.KIND, ffn[0].KIND if ffn else None, False)
+        if want != (s.mixer, s.ffn, s.cross_attn):
+            bad[f"layer {i}"] = (kind, (s.mixer, s.ffn, s.cross_attn))
+    for part in {p for kind in spec.pattern(m) for p in spec.parts_of(kind)}:
+        bad.update(part.check(cfg, m))
+    if bad:
         raise ValueError(f"{c['name']}: the program's {cfg.name} differs "
                          f"from the configuration: {bad}")
     return cfg

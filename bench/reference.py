@@ -6,12 +6,15 @@ seed, one layer at a time inside that layer's jitted call
 (``weights.layer_weights``), so nothing the program made is read and only
 one layer's weights are on the device at once.
 
-The model: token embedding; ``n_layers`` pre-norm blocks (RMSNorm,
-rotary attention with grouped kv heads, SwiGLU MLP); a final RMSNorm and
-the LM head (the embedding table when tied). Layer-parallel (LP) pairs
-``(i, i + 1)`` from the configuration run side by side on the same input:
-``a = x + A_i(N1_i x) + A_{i+1}(N1_{i+1} x)``, then
-``y = a + F_i(N2_i a) + F_{i+1}(N2_{i+1} a)`` (arXiv:2502.02790).
+The model: token embedding; ``n_layers`` pre-norm blocks, each a mixer
+``A`` and an FFN ``F`` or none, computed by the layer's parts
+(``bench/parts/``, named by the configuration's ``pattern``; by default
+rotary attention with grouped kv heads and a SwiGLU MLP) between RMSNorms;
+a final RMSNorm and the LM head (the embedding table when tied).
+Layer-parallel (LP) pairs ``(i, i + 1)`` from the configuration run side
+by side on the same input: ``a = x + A_i(N1_i x) + A_{i+1}(N1_{i+1} x)``,
+then ``y = a + F_i(N2_i a) + F_{i+1}(N2_{i+1} a)`` (arXiv:2502.02790); a
+single layer is the same with one term, and ``y = a`` without an FFN.
 
 ``quant=True`` is the control: the same computation with both inputs of
 every matmul rounded to float8 (e4m3; one scale per weight column and per
@@ -21,11 +24,13 @@ in, one step below the configuration's bfloat16.
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+import spec
 from weights import TOP, draw, layer_weights, top_shapes
 
 HI = jax.lax.Precision.HIGHEST
@@ -48,66 +53,42 @@ def _rms(x, s, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * s
 
 
-def _rope(x, pos, theta):
-    """x [L, H, hd]; rotate halves (Llama convention)."""
-    hd = x.shape[-1]
-    freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = pos[:, None].astype(jnp.float32) * freqs
-    c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1)
+def _weights(key, layer, m, kind):
+    return {n: a.astype(jnp.float32) for n, a in layer_weights(
+        key, layer, m, jnp.dtype(m["dtype"]), kind).items()}
 
 
-def _attention(xn, w, m, quant):
-    """xn [L, D] of one sequence -> [L, D]; causal, grouped kv heads."""
-    L = xn.shape[0]
-    hd, hq, hkv = m["head_dim"], m["n_heads"], m["n_kv_heads"]
-    pos = jnp.arange(L)
-    q = _mm(xn, w["wq"], quant).reshape(L, hkv, hq // hkv, hd)
-    k = _mm(xn, w["wk"], quant).reshape(L, hkv, hd)
-    v = _mm(xn, w["wv"], quant).reshape(L, hkv, hd)
-    q = _rope(q.reshape(L, hq, hd), pos, m["rope_theta"]).reshape(q.shape)
-    k = _rope(k, pos, m["rope_theta"])
-    s = jnp.einsum("qngh,knh->ngqk", q, k, precision=HI) * hd ** -0.5
-    s = jnp.where(pos[None, None, :, None] >= pos[None, None, None, :], s,
-                  -jnp.inf)
-    o = jnp.einsum("ngqk,knh->qngh", jax.nn.softmax(s, -1), v, precision=HI)
-    return _mm(o.reshape(L, hq * hd), w["wo"], quant)
-
-
-def _mlp(xn, w, quant):
-    g = _mm(xn, w["w_gate"], quant)
-    u = _mm(xn, w["w_up"], quant)
-    return _mm(jax.nn.silu(g) * u, w["w_down"], quant)
-
-
-def _weights(key, layer, m):
-    return {n: a.astype(jnp.float32) for n, a in
-            layer_weights(key, layer, m, jnp.dtype(m["dtype"])).items()}
-
-
-def _group(x, key, layers, m, quant):
-    """One block (one layer) or LP pair (two layers) over x [n, L, D]."""
+def _group(x, key, layers, kinds, m, quant):
+    """One block (one layer) or LP pair (two layers) over x [n, L, D];
+    ``kinds``: each layer's ``(mixer, ffn)``."""
     eps = m["rms_norm_eps"]
-    ws = [_weights(key, i, m) for i in layers]
-    # One sequence at a time through attention: its scores are [H, L, L].
+    ws = [_weights(key, i, m, k) for i, k in zip(layers, kinds)]
+    parts = [spec.parts_of(k) for k in kinds]      # [mixer] or [mixer, ffn]
+    # One sequence at a time through a mixer: attention's scores are
+    # [H, L, L], a scan's state runs along the sequence.
     a = x + sum(jax.lax.map(
-        lambda xs, w=w: _attention(_rms(xs, w["ln1"], eps), w, m, quant), x)
-        for w in ws)
-    return a + sum(_mlp(_rms(a, w["ln2"], eps), w, quant) for w in ws)
+        lambda xs, w=w, p=p: p[0].ref(_rms(xs, w["ln1"], eps), w, m, quant),
+        x) for w, p in zip(ws, parts))
+    ffns = [(w, p[1]) for w, p in zip(ws, parts) if len(p) > 1]
+    if not ffns:
+        return a
+    return a + sum(f.ref(_rms(a, w["ln2"], eps), w, m, quant)
+                   for w, f in ffns)
 
 
 @functools.lru_cache(maxsize=None)
 def _programs(mkey, quant):
-    m = dict(mkey)
+    m = json.loads(mkey)
     dt = jnp.dtype(m["dtype"])
 
     def embed(key, seqs):
         return draw(key, TOP, "tok", top_shapes(m)["tok"], dt
                     ).astype(jnp.float32)[seqs]
 
-    single = jax.jit(lambda x, key, i: _group(x, key, (i,), m, quant))
-    pair = jax.jit(lambda x, key, i: _group(x, key, (i, i + 1), m, quant))
+    single = jax.jit(lambda x, key, i, k: _group(x, key, (i,), k, m, quant),
+                     static_argnums=3)
+    pair = jax.jit(lambda x, key, i, k: _group(x, key, (i, i + 1), k, m,
+                                               quant), static_argnums=3)
 
     def head(key, x, pos):
         """Hidden [n, L, D] at ``pos`` [n, P] -> logits [n, P, V]."""
@@ -124,11 +105,8 @@ def _programs(mkey, quant):
 
 
 def _model_key(m):
-    keep = ("d_model", "n_layers", "n_heads", "n_kv_heads", "head_dim",
-            "d_ff", "vocab_size", "rope_theta", "rms_norm_eps",
-            "tie_embeddings", "dtype")
-    return tuple((k, m[k]) for k in keep) + (
-        ("lp_pairs", tuple(map(tuple, m["lp_pairs"]))),)
+    """The whole model dict, pattern and each part's keys with it."""
+    return json.dumps(m, sort_keys=True)
 
 
 def hidden_logits(m: dict, key, seqs, pos, *, quant=False):
@@ -140,10 +118,11 @@ def hidden_logits(m: dict, key, seqs, pos, *, quant=False):
     i = 0
     while i < m["n_layers"]:
         if i in starts:
-            x = pair(x, key, jnp.int32(i))
+            x = pair(x, key, jnp.int32(i), (spec.layer_kind(m, i),
+                                            spec.layer_kind(m, i + 1)))
             i += 2
         else:
-            x = single(x, key, jnp.int32(i))
+            x = single(x, key, jnp.int32(i), (spec.layer_kind(m, i),))
             i += 1
     return head(key, x, jnp.asarray(pos))
 

@@ -10,13 +10,23 @@
   its traffic mix's, such as the arrival rate found for that model;
 - ``bench/metrics/<metric>.py``: one reader per metric, ``read(run)``
   returning a number or None;
+- ``bench/parts/<part>.py``: one module per part of a layer, a mixer
+  (``attn``, ``mamba``) or an FFN (``mlp``): its leaves, the program's
+  layer kind it computes, its float32 forward for the reference and its
+  FLOP and byte counts (``parts/attn.py`` says what a part module holds).
+  A configuration names each layer's parts in its model's optional
+  ``pattern``, a list of ``{"mixer": <part>, "ffn": <part> | null}``
+  repeated to ``n_layers`` as the program repeats its block pattern;
+  without it every layer is ``attn`` and ``mlp``;
 - ``bench/peaks.json``: the chip's published peaks by ``device_kind``.
 
-A new configuration, traffic mix, cell or metric is a new file here and a
-new entry in ``BENCHMARK.json``; no code changes.
+A new configuration, traffic mix, cell, metric or layer part is a new
+file here and, but for a part, a new entry in ``BENCHMARK.json``; no code
+changes.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -56,6 +66,49 @@ def load_metric(name: str, here: str = HERE):
     mod = importlib.util.module_from_spec(sp)
     sp.loader.exec_module(mod)
     return mod
+
+
+#: A model's layers when it names no ``pattern``: the dense decoder layer.
+DENSE = ({"mixer": "attn", "ffn": "mlp"},)
+
+
+def pattern(m: dict) -> tuple:
+    """The model dict's layer pattern as ``(mixer, ffn)`` part names (the
+    FFN None where the layer has none), one entry per pattern position."""
+    return tuple((e["mixer"], e["ffn"]) for e in m.get("pattern", DENSE))
+
+
+def layer_kind(m: dict, layer: int) -> tuple:
+    """``(mixer, ffn)`` part names of layer ``layer``."""
+    pat = pattern(m)
+    return pat[layer % len(pat)]
+
+
+@functools.lru_cache(maxsize=None)
+def load_part(name: str, here: str = HERE):
+    """The module of layer part ``name``; a name with no file is an error
+    that names the file it looked for."""
+    path = os.path.join(here, "parts", f"{name}.py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no layer part {name!r}: {path} is missing")
+    sp = importlib.util.spec_from_file_location(
+        "bench_part_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(sp)
+    sp.loader.exec_module(mod)
+    return mod
+
+
+def parts_of(kind: tuple, here: str = HERE) -> list:
+    """The part modules of one layer kind ``(mixer, ffn)``: the mixer,
+    then the FFN where there is one."""
+    return [load_part(n, here) for n in kind if n is not None]
+
+
+def all_parts(here: str = HERE) -> list:
+    """Every part module under ``parts/``, by file name."""
+    d = os.path.join(here, "parts")
+    return [load_part(f[:-3], here) for f in sorted(os.listdir(d))
+            if f.endswith(".py")]
 
 
 def peaks(kind: str, here: str = HERE) -> dict:

@@ -1,6 +1,8 @@
-"""A tiny cell on the CPU: the benchmark's harness driven end to end at a
+"""Tiny cells on the CPU: the benchmark's harness driven end to end at a
 size a test run holds (4 layers, d_model 64, one LP pair), from files
-written into a temporary directory laid out like ``bench/``."""
+written into a temporary directory laid out like ``bench/``: the dense
+``tiny`` configuration under every traffic kind, and ``tinymamba``, the
+program's Falcon-Mamba layer (a Mamba-1 mixer and no FFN), under chat."""
 from __future__ import annotations
 
 import json
@@ -14,10 +16,28 @@ MODEL = {"d_model": 64, "n_layers": 4, "n_heads": 4, "n_kv_heads": 2,
          "head_dim": 16, "d_ff": 128, "vocab_size": 256,
          "rope_theta": 10000.0, "rms_norm_eps": 1e-6,
          "tie_embeddings": False, "dtype": "bfloat16", "lp_pairs": [[1, 2]]}
-#: The tiny cell's limit on the widest gap, in sigma: the program read
-#: 0.005-0.038 over 140-160 checked tokens on three seeds here, its fp8
-#: control 0.43-0.76.
+#: The tiny cells' limit on the widest gap, in sigma. ``tiny``: the
+#: program read 0.005-0.038 over 140-160 checked tokens on three seeds
+#: here, its fp8 control 0.43-0.76. ``tinymamba``: see ``MAMBA_CELL``.
 LIMIT = 0.15
+
+#: Served in float32: the program's paged cache keeps Mamba's scan state in
+#: the cache dtype, and its decode scan refuses a bfloat16 carry.
+MAMBA = {"d_model": 64, "n_layers": 4, "vocab_size": 256, "ssm_state": 8,
+         "ssm_conv": 4, "ssm_expand": 2, "dt_rank": 8,
+         "rms_norm_eps": 1e-6, "tie_embeddings": False, "dtype": "float32",
+         "lp_pairs": [[1, 2]], "pattern": [{"mixer": "mamba", "ffn": None}]}
+
+#: The Mamba cell's own parameters over the chat mix. The program gives a
+#: Mamba layer no bucket ladder, so each new prompt length compiles in the
+#: window: five lengths and a long drain let every request finish on a
+#: loaded CPU, and every finished one is checked. Its limit is the dense
+#: cell's: over 266 checked tokens on four seeds the program read 0.0
+#: (served in float32, it picks the reference's first choice every time),
+#: its fp8 control 0.37-0.75.
+MAMBA_CELL = {"gap_limit_sigma": LIMIT,
+              "prompt": {"dist": "loguniform", "min": 12, "max": 16},
+              "drain_s": 60, "check_requests": 1000}
 
 TRAFFIC = {
     "chat": {"kind": "open_loop", "base_seed": 5, "rate_per_s": 20.0,
@@ -58,6 +78,15 @@ def write(root: str) -> str:
                      "prefix_cache": True}}
     with open(os.path.join(here, "configs", "tiny.json"), "w") as f:
         json.dump(cfg, f)
+    mamba = {"name": "tinymamba", "program_arch": "falcon-mamba-7b",
+             "program_overrides": {k: MAMBA[k] for k in (
+                 "d_model", "n_layers", "vocab_size", "ssm_state",
+                 "dt_rank")},
+             "model": MAMBA, "serve": dict(cfg["serve"], prefix_cache=False)}
+    with open(os.path.join(here, "configs", "tinymamba.json"), "w") as f:
+        json.dump(mamba, f)
+    with open(os.path.join(here, "cells", "tinymamba.chat.json"), "w") as f:
+        json.dump(MAMBA_CELL, f)
     cells = []
     for name, t in TRAFFIC.items():
         with open(os.path.join(here, "traffic", f"{name}.json"), "w") as f:
@@ -66,9 +95,11 @@ def write(root: str) -> str:
             json.dump({"gap_limit_sigma": LIMIT}, f)
         cells.append({"name": f"tiny.{name}", "config": "tiny",
                       "traffic": name, "chips": 1, "why": "test"})
-    lat = ["tiny.chat", "tiny.sessions"]
+    lat = ["tiny.chat", "tiny.sessions", "tinymamba.chat"]
     bench = {
-        "workloads": cells,
+        "workloads": cells + [{"name": "tinymamba.chat",
+                               "config": "tinymamba", "traffic": "chat",
+                               "chips": 1, "why": "test"}],
         "end_to_end": [
             {"name": "ttft_p90_ms", "unit": "ms", "workloads": lat},
             {"name": "itl_p99_ms", "unit": "ms", "workloads": lat},

@@ -70,10 +70,10 @@ def test_benchmark_json_shape():
 
 
 def test_lookup_by_name_from_new_files_alone(tmp_path):
-    """A new configuration, traffic mix and cell are new files and new
-    entries; the resolver finds them with no code change."""
+    """A new configuration, traffic mix, cell and layer part are new files
+    and new entries; the resolver finds them with no code change."""
     here = tmp_path / "bench"
-    for d in ("configs", "traffic", "cells"):
+    for d in ("configs", "traffic", "cells", "parts"):
         (here / d).mkdir(parents=True)
     (here / "configs" / "newcfg.json").write_text(json.dumps(
         {"name": "newcfg", "model": {}}))
@@ -95,6 +95,19 @@ def test_lookup_by_name_from_new_files_alone(tmp_path):
     assert [m["name"] for m in r["per_layer"]] == ["queue_wait_p90_ms"]
     with pytest.raises(KeyError):
         spec.resolve("nope", bench, str(here))
+    (here / "parts" / "newffn.py").write_text(
+        'KIND = "moe"\nIDS = {"router": 99}\n'
+        'def shapes(m):\n    return {"ln2": (m["d_model"],)}\n')
+    m = {"d_model": 8, "pattern": [{"mixer": "attn", "ffn": "newffn"}]}
+    kind = spec.layer_kind(m, 5)
+    assert kind == ("attn", "newffn")
+    with pytest.raises(FileNotFoundError, match="attn.py"):
+        spec.parts_of(kind, str(here))
+    (here / "parts" / "attn.py").write_text('KIND = "attn"\n')
+    mixer, ffn = spec.parts_of(kind, str(here))
+    assert (mixer.KIND, ffn.KIND) == ("attn", "moe")
+    assert ffn.shapes(m) == {"ln2": (8,)} and ffn.IDS == {"router": 99}
+    assert [p.KIND for p in spec.all_parts(str(here))] == ["attn", "moe"]
 
 
 def _run(cwd, env_extra=None):
